@@ -270,7 +270,7 @@ def bench_queries(mesh, view, g, n_sources, bc_chunk):
                              src_chunk=bc_chunk)
     permute = coll.get("collective-permute", 0)
     n_dev = view.n_shards
-    per_rot = (view.band * view.vp * 4
+    per_rot = (view.band * view.vp            # int8 {0,1} band
                + view.rows_per_shard * view.n_tiles * 4)  # O(Vp^2/n)
     mem_g = _temp_bytes(mesh, view, g, "bc", srcs, src_chunk=bc_chunk)
     mem_r = _temp_bytes(mesh, view, g, "bc_ring", srcs, src_chunk=bc_chunk)

@@ -30,13 +30,15 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 # Sentinel for empty edge slots / invalid vertex ids.  Must be the maximum
 # int32 so empty slots sort after every real key.
 NOKEY: int = 2**31 - 1
 # Weight tombstone: logically-removed edge (and "no edge" in dense form).
-INF = jnp.float32(jnp.inf)
+# A host scalar, so importing the package starts no JAX backend.
+INF = np.float32(np.inf)
 
 
 class GraphState(NamedTuple):
@@ -194,8 +196,6 @@ def densify(state: GraphState) -> jax.Array:
 
 def from_edge_list(vcap: int, ecap: int, src, dst, w=None) -> GraphState:
     """Build a committed graph from host edge arrays (bulk load)."""
-    import numpy as np
-
     src = np.asarray(src, np.int32)
     dst = np.asarray(dst, np.int32)
     if w is None:

@@ -37,7 +37,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from .graph_state import INF, NOKEY, GraphState
 
@@ -167,12 +166,12 @@ def make_distributed_query(mesh: Mesh, query: str = "bfs"):
     vspec = P()              # vertex arrays: replicated
     body = {"bfs": _bfs_sharded, "sssp": _sssp_sharded}[query]
 
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(body, axes=axes),
         mesh=mesh,
         in_specs=(vspec, vspec, espec, espec, espec, vspec),
         out_specs=vspec,
-        check_rep=False,
+        check_vma=False,
     )
     in_sh = (
         NamedSharding(mesh, vspec), NamedSharding(mesh, vspec),

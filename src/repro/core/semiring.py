@@ -57,15 +57,19 @@ def bool_mm(f: jax.Array, a: jax.Array, use_kernel: bool = False,
     if amask is None:
         return (jnp.dot(f, a, precision=jax.lax.Precision.HIGHEST) > 0
                 ).astype(jnp.float32)
-    acc = _masked_count_accum(f.astype(jnp.float32), a.astype(jnp.float32),
-                              amask, tile, "bool_mm")
+    acc = _masked_count_accum(f.astype(jnp.float32), a, amask, tile,
+                              "bool_mm")
     return (acc > 0).astype(jnp.float32)
 
 
 def _masked_count_accum(fp_in: jax.Array, ap_in: jax.Array, amask: jax.Array,
                         tile: int, name: str) -> jax.Array:
     """Shared k-slab-skipping sum-of-dots: the masked fallback body of both
-    ``bool_mm`` (which thresholds the result) and ``count_mm``."""
+    ``bool_mm`` (which thresholds the result) and ``count_mm``.
+
+    ``ap_in`` may be any dtype that holds its values exactly (the sharded
+    ring passes int8 {0,1} bands); each k slab is widened to f32 only as
+    it is used, so no f32 copy of the whole operand is ever made."""
     _check_amask(amask, ap_in.shape[0], ap_in.shape[1], tile, name)
     fp = _pad_axis(fp_in, 1, tile, 0.0)
     ap = _pad_axis(ap_in, 0, tile, 0.0)
@@ -77,7 +81,7 @@ def _masked_count_accum(fp_in: jax.Array, ap_in: jax.Array, amask: jax.Array,
         ak = lax.dynamic_slice_in_dim(ap, i * tile, tile, axis=0)
         return lax.cond(
             krow[i] & (fk != 0).any(),
-            lambda acc: acc + jnp.dot(fk, ak,
+            lambda acc: acc + jnp.dot(fk, ak.astype(jnp.float32),
                                       precision=jax.lax.Precision.HIGHEST),
             lambda acc: acc, acc)
 
@@ -128,5 +132,5 @@ def count_mm(s: jax.Array, a: jax.Array, use_kernel: bool = False,
         return kops.count_mm(s, a, amask=amask, tile=tile)
     if amask is None:
         return jnp.dot(s, a, precision=jax.lax.Precision.HIGHEST)
-    return _masked_count_accum(s.astype(jnp.float32), a.astype(jnp.float32),
-                               amask, tile, "count_mm")
+    return _masked_count_accum(s.astype(jnp.float32), a, amask, tile,
+                               "count_mm")

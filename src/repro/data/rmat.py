@@ -35,9 +35,14 @@ def rmat_edges(n_vertices: int, n_edges: int, a=0.5, b=0.1, c=0.1, d=0.3,
 
 
 def load_rmat_graph(n_vertices: int, n_edges: int, slack: float = 1.5,
-                    seed: int = 0, weighted: bool = True) -> GraphState:
+                    seed: int = 0, weighted: bool = True,
+                    initiator=(0.5, 0.1, 0.1, 0.3)) -> GraphState:
     """Paper Table-1 style initial graph, with edge-capacity slack for the
-    dynamic-update workload."""
-    src, dst, w = rmat_edges(n_vertices, n_edges, seed=seed, weighted=weighted)
+    dynamic-update workload.  ``initiator`` is the (a, b, c, d) quadrant
+    split: the paper's by default, or Graph500's Kronecker
+    (0.57, 0.19, 0.19, 0.05)."""
+    a, b, c, d = initiator
+    src, dst, w = rmat_edges(n_vertices, n_edges, a, b, c, d, seed=seed,
+                             weighted=weighted)
     ecap = int(n_edges * slack)
     return from_edge_list(n_vertices, ecap, src, dst, w)

@@ -1,4 +1,5 @@
-"""Backend detection + shared guards for the kernel modules.
+"""Backend detection, shared guards and the shared Pallas call of the
+semiring kernel modules.
 
 Lives in its own module (rather than ``ops.py``) so the raw kernel modules
 can default to the detected mode without importing ``ops`` back — ``ops``
@@ -7,11 +8,27 @@ imports the kernel modules, and a reverse import would be a cycle.
 from __future__ import annotations
 
 import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-# Pallas kernels compile only on TPU; everywhere else (this CPU container
-# included) they run the kernel body in interpret mode, which is what the
-# oracle tests validate against.
-INTERPRET: bool = jax.default_backend() != "tpu"
+
+def interpret_mode() -> bool:
+    """Whether Pallas kernels run their body in interpret mode.
+
+    Pallas kernels compile only on TPU; on every other backend they run
+    the kernel body in interpret mode, which is what the oracle tests
+    validate against.  Asked at trace time, never at import: importing
+    the package must not start a JAX backend (on a TPU host that would
+    claim the chip for a process that only imported the code).
+    """
+    return jax.default_backend() != "tpu"
+
+
+def resolve_interpret(interpret: bool | None) -> bool:
+    """A raw kernel's ``interpret`` argument: ``None`` means the backend's
+    default (:func:`interpret_mode`)."""
+    return interpret_mode() if interpret is None else interpret
 
 
 def check_blocks(name: str, s: int, kdim: int, n: int,
@@ -45,3 +62,57 @@ def check_amask(name: str, amask_shape, kdim: int, n: int, tile: int) -> None:
             f"{name}: amask shape {tuple(amask_shape)} does not tile the "
             f"({kdim}, {n}) operand at tile={tile} (expected {expect}); "
             "was the tile view built with a different tile size?")
+
+
+def semiring_call(name: str, body, x: jax.Array, y: jax.Array,
+                  xmask: jax.Array | None, ymask: jax.Array | None, *,
+                  bm: int, bn: int, bk: int,
+                  interpret: bool | None) -> jax.Array:
+    """The blocked ``(S, K) x (K, N) -> (S, N)`` f32 Pallas call shared by
+    the three semiring kernels.
+
+    Grid = (S/bm, N/bn, K/bk) with k innermost, so the output tile stays
+    in VMEM across the k sweep.  ``body(x_ref, y_ref, o_ref, live, nk)``
+    is the per-step kernel: ``live`` is ``True`` for the dense product,
+    or, with ``xmask`` ([S/bm, K/bk]) and ``ymask`` ([K/bk, N/bn]), the
+    traced predicate "both blocks hold a non-identity entry" read from
+    the scalar-prefetched occupancy grids.  The grids ride in SMEM as
+    flat int32 vectors: a ``(1, 1)`` SMEM block per step breaks Mosaic's
+    (8, 128) block-shape rule, and a 2-D SMEM array pads its last dim.
+    A zero mask MUST imply an all-identity block.
+    """
+    s, kdim = x.shape
+    _, n = y.shape
+    bm, bn, bk = min(bm, s), min(bn, n), min(bk, kdim)
+    check_blocks(name, s, kdim, n, bm, bk, bn)
+    grid = (s // bm, n // bn, kdim // bk)
+    nk, nn = grid[2], grid[1]
+    masked = xmask is not None
+    if masked and (xmask.shape != (grid[0], nk) or ymask.shape != (nk, nn)):
+        raise ValueError(
+            f"{name}_masked: mask shapes {xmask.shape}/{ymask.shape} do not "
+            f"match the block grid ({grid[0]}, {nk})/({nk}, {nn})")
+
+    def kernel(*refs):
+        if masked:
+            xm_ref, ym_ref, x_ref, y_ref, o_ref = refs
+            i, j, k = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+            live = (xm_ref[i * nk + k] > 0) & (ym_ref[k * nn + j] > 0)
+        else:
+            x_ref, y_ref, o_ref = refs
+            live = True
+        body(x_ref, y_ref, o_ref, live, nk)
+
+    spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2 if masked else 0,
+        grid=grid,
+        in_specs=[pl.BlockSpec((bm, bk), lambda i, j, k, *_: (i, k)),
+                  pl.BlockSpec((bk, bn), lambda i, j, k, *_: (k, j))],
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k, *_: (i, j)))
+    masks = ((xmask.reshape(-1).astype(jnp.int32),
+              ymask.reshape(-1).astype(jnp.int32)) if masked else ())
+    return pl.pallas_call(
+        kernel, grid_spec=spec,
+        out_shape=jax.ShapeDtypeStruct((s, n), jnp.float32),
+        interpret=resolve_interpret(interpret),
+    )(*masks, x, y)

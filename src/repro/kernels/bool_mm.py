@@ -9,11 +9,11 @@ VMEM working set per step = bm*bk + bk*bn + bm*bn floats ~= (128*512*2 +
 128*128)*4B ~= 0.6 MB, far under the ~16 MB/core budget, leaving room for
 double buffering of the HBM->VMEM pipeline.
 
-``bool_mm_masked`` adds SMEM occupancy grids (frontier slab nonzero, weight
-tile holds a live edge) and skips the MXU dot where either is empty — a
-skipped contribution is all-zero, the (or, and) semiring identity, so the
-accumulator is untouched.  Init (k == 0) and the threshold epilogue
-(k == nk-1) stay unconditional.
+``bool_mm_masked`` adds scalar-prefetched occupancy grids (frontier slab
+nonzero, weight tile holds a live edge) and skips the MXU dot where either
+is empty — a skipped contribution is all-zero, the (or, and) semiring
+identity, so the accumulator is untouched.  Init (k == 0) and the
+threshold epilogue (k == nk-1) stay unconditional.
 """
 from __future__ import annotations
 
@@ -22,38 +22,22 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from .backend import INTERPRET, check_blocks
+from .backend import semiring_call
 
 DEFAULT_BM = 128
 DEFAULT_BN = 128
 DEFAULT_BK = 512
 
 
-def _kernel(f_ref, a_ref, o_ref, *, nk: int):
+def _body(f_ref, a_ref, o_ref, live, nk: int):
     k = pl.program_id(2)
 
     @pl.when(k == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    o_ref[...] += jnp.dot(f_ref[...], a_ref[...],
-                          preferred_element_type=jnp.float32)
-
-    @pl.when(k == nk - 1)
-    def _epilogue():
-        o_ref[...] = (o_ref[...] > 0).astype(jnp.float32)
-
-
-def _masked_kernel(fm_ref, am_ref, f_ref, a_ref, o_ref, *, nk: int):
-    k = pl.program_id(2)
-
-    @pl.when(k == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    @pl.when((fm_ref[0, 0] > 0) & (am_ref[0, 0] > 0))
+    @pl.when(live)
     def _compute():
         o_ref[...] += jnp.dot(f_ref[...], a_ref[...],
                               preferred_element_type=jnp.float32)
@@ -66,62 +50,25 @@ def _masked_kernel(fm_ref, am_ref, f_ref, a_ref, o_ref, *, nk: int):
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
 def bool_mm(f: jax.Array, a: jax.Array, *, bm: int = DEFAULT_BM,
             bn: int = DEFAULT_BN, bk: int = DEFAULT_BK,
-            interpret: bool = INTERPRET) -> jax.Array:
+            interpret: bool | None = None) -> jax.Array:
     """f: [S, V] {0,1} f32; a: [V, V'] {0,1} f32 -> [S, V'] {0,1} f32.
 
     Shapes must be multiples of the block sizes (``ops.bool_mm`` pads).
     """
-    s, kdim = f.shape
-    _, n = a.shape
-    bm, bn, bk = min(bm, s), min(bn, n), min(bk, kdim)
-    check_blocks("bool_mm", s, kdim, n, bm, bk, bn)
-    grid = (s // bm, n // bn, kdim // bk)
-    return pl.pallas_call(
-        functools.partial(_kernel, nk=grid[2]),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((s, n), jnp.float32),
-        interpret=interpret,
-    )(f, a)
+    return semiring_call("bool_mm", _body, f, a, None, None, bm=bm, bn=bn,
+                         bk=bk, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
 def bool_mm_masked(f: jax.Array, a: jax.Array, fmask: jax.Array,
                    amask: jax.Array, *, bm: int = DEFAULT_BM,
                    bn: int = DEFAULT_BN, bk: int = DEFAULT_BK,
-                   interpret: bool = INTERPRET) -> jax.Array:
+                   interpret: bool | None = None) -> jax.Array:
     """Tile-skipping boolean-semiring product.
 
     ``fmask``: int32 [S/bm, K/bk] — nonzero iff the frontier slab has any
     set bit; ``amask``: int32 [K/bk, N/bn] — nonzero iff the adjacency tile
     has any live edge.  A zero mask MUST imply an all-zero block.
     """
-    s, kdim = f.shape
-    _, n = a.shape
-    bm, bn, bk = min(bm, s), min(bn, n), min(bk, kdim)
-    check_blocks("bool_mm", s, kdim, n, bm, bk, bn)
-    grid = (s // bm, n // bn, kdim // bk)
-    if fmask.shape != (grid[0], grid[2]) or amask.shape != (grid[2], grid[1]):
-        raise ValueError(
-            f"bool_mm_masked: mask shapes {fmask.shape}/{amask.shape} do "
-            f"not match the block grid ({grid[0]}, {grid[2]})/"
-            f"({grid[2]}, {grid[1]})")
-    return pl.pallas_call(
-        functools.partial(_masked_kernel, nk=grid[2]),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i, j, k: (i, k),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1), lambda i, j, k: (k, j),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((s, n), jnp.float32),
-        interpret=interpret,
-    )(fmask.astype(jnp.int32), amask.astype(jnp.int32), f, a)
+    return semiring_call("bool_mm", _body, f, a, fmask, amask, bm=bm, bn=bn,
+                         bk=bk, interpret=interpret)

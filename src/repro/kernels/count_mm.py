@@ -10,8 +10,8 @@ this reproduction targets.
 Grid = (S/bm, V/bn, V/bk), k innermost with VMEM accumulation, identical to
 ``bool_mm`` minus the threshold epilogue.  ``count_mm_masked`` skips the MXU
 dot for (slab, tile) pairs whose occupancy masks say the contribution is
-all-zero (the (+, x) semiring identity), driven by the same SMEM occupancy
-grids as the other masked kernels.
+all-zero (the (+, x) semiring identity), driven by the same
+scalar-prefetched occupancy grids as the other masked kernels.
 """
 from __future__ import annotations
 
@@ -20,67 +20,45 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from .backend import INTERPRET, check_blocks
+from .backend import semiring_call
 
 DEFAULT_BM = 128
 DEFAULT_BN = 128
 DEFAULT_BK = 512
 
 
-def _kernel(s_ref, a_ref, o_ref):
+def _body(s_ref, a_ref, o_ref, live, nk: int):
+    del nk
     k = pl.program_id(2)
 
     @pl.when(k == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    o_ref[...] += jnp.dot(s_ref[...], a_ref[...],
-                          preferred_element_type=jnp.float32)
-
-
-def _masked_kernel(sm_ref, am_ref, s_ref, a_ref, o_ref):
-    k = pl.program_id(2)
-
-    @pl.when(k == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    @pl.when((sm_ref[0, 0] > 0) & (am_ref[0, 0] > 0))
+    @pl.when(live)
     def _compute():
+        # Full f32 passes: sigma counts above 256 are not exact in a
+        # single bf16 pass, and the counting semiring must be exact.
         o_ref[...] += jnp.dot(s_ref[...], a_ref[...],
+                              precision=jax.lax.Precision.HIGHEST,
                               preferred_element_type=jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
 def count_mm(s: jax.Array, a: jax.Array, *, bm: int = DEFAULT_BM,
              bn: int = DEFAULT_BN, bk: int = DEFAULT_BK,
-             interpret: bool = INTERPRET) -> jax.Array:
+             interpret: bool | None = None) -> jax.Array:
     """s: [S, V] f32 counts; a: [V, V'] f32 -> [S, V'] f32 (plain matmul)."""
-    m, kdim = s.shape
-    _, n = a.shape
-    bm, bn, bk = min(bm, m), min(bn, n), min(bk, kdim)
-    check_blocks("count_mm", m, kdim, n, bm, bk, bn)
-    grid = (m // bm, n // bn, kdim // bk)
-    return pl.pallas_call(
-        _kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        interpret=interpret,
-    )(s, a)
+    return semiring_call("count_mm", _body, s, a, None, None, bm=bm, bn=bn,
+                         bk=bk, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
 def count_mm_masked(s: jax.Array, a: jax.Array, smask: jax.Array,
                     amask: jax.Array, *, bm: int = DEFAULT_BM,
                     bn: int = DEFAULT_BN, bk: int = DEFAULT_BK,
-                    interpret: bool = INTERPRET) -> jax.Array:
+                    interpret: bool | None = None) -> jax.Array:
     """Tile-skipping counting product.
 
     ``smask``: int32 [S/bm, K/bk] — nonzero iff the count slab has any
@@ -88,28 +66,5 @@ def count_mm_masked(s: jax.Array, a: jax.Array, smask: jax.Array,
     adjacency tile has any live edge.  A zero mask MUST imply an all-zero
     block.
     """
-    m, kdim = s.shape
-    _, n = a.shape
-    bm, bn, bk = min(bm, m), min(bn, n), min(bk, kdim)
-    check_blocks("count_mm", m, kdim, n, bm, bk, bn)
-    grid = (m // bm, n // bn, kdim // bk)
-    if smask.shape != (grid[0], grid[2]) or amask.shape != (grid[2], grid[1]):
-        raise ValueError(
-            f"count_mm_masked: mask shapes {smask.shape}/{amask.shape} do "
-            f"not match the block grid ({grid[0]}, {grid[2]})/"
-            f"({grid[2]}, {grid[1]})")
-    return pl.pallas_call(
-        _masked_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i, j, k: (i, k),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1), lambda i, j, k: (k, j),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        interpret=interpret,
-    )(smask.astype(jnp.int32), amask.astype(jnp.int32), s, a)
+    return semiring_call("count_mm", _body, s, a, smask, amask, bm=bm,
+                         bn=bn, bk=bk, interpret=interpret)

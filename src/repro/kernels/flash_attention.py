@@ -23,7 +23,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .backend import INTERPRET
+from .backend import resolve_interpret
 
 
 DEFAULT_BQ = 128
@@ -89,7 +89,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, sm_scale: float | None = None,
                     window: int | None = None,
                     bq: int = DEFAULT_BQ, bk: int = DEFAULT_BK,
-                    interpret: bool = INTERPRET) -> jax.Array:
+                    interpret: bool | None = None) -> jax.Array:
     """q: [B, Hq, Sq, D]; k, v: [B, Hkv, Skv, D] -> [B, Hq, Sq, D].
 
     Causal alignment matches the oracle: query i sees kv j iff
@@ -138,7 +138,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq_, 1), jnp.float32),
             pltpu.VMEM((bq_, d), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qf, kf, vf)
     return out.reshape(b, hq, sq_p, d)[:, :, :sq, :]
 

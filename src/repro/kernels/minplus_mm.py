@@ -1,21 +1,23 @@
 """Pallas TPU kernel: tropical (min,+) blocked matmul (SSSP relaxation).
 
 out[s, j] = min_k d[s, k] + w[k, j].  No MXU analogue exists for (min,+), so
-the inner product runs on the VPU via a broadcast-add + min-reduce over a
-*small* k slab (bk=16) to bound the (bm, bk, bn) broadcast working set:
-128*16*128*4B = 1 MB in VMEM.  Grid = (S/bm, V/bn, V/bk), k innermost with
-output-tile accumulation (running elementwise min) across the k sweep.
+the inner product runs on the VPU.  The k block is lane-aligned (bk=128:
+Mosaic requires a block's last dim to be a multiple of 128) and the kernel
+walks it in ``SUB``-row sub-slabs, each a broadcast-add + min-reduce whose
+(bm, SUB, bn) working set is 128*8*128*4B = 0.5 MB of VMEM.  Grid = (S/bm,
+V/bn, V/bk), k innermost with output-tile accumulation (running elementwise
+min) across the k sweep.
 
 +inf entries (absent edges / unreached sources) flow through min() untouched,
 so the tombstone encoding of the graph state needs no special-casing.
 
-``minplus_mm_masked`` is the tile-skipping variant: two scalar occupancy
-grids ride along in SMEM — ``dmask[S/bm, K/bk]`` (frontier slab holds any
-finite distance) and ``wmask[K/bk, N/bn]`` (weight tile holds any live
-edge) — and a ``pl.when`` guard skips the broadcast-min for (slab, tile)
-pairs whose product is all-+inf, i.e. the semiring identity.  Output-tile
-init still runs at k == 0, so a fully skipped output tile is +inf, exactly
-what the dense kernel computes for it.
+``minplus_mm_masked`` is the tile-skipping variant: two scalar-prefetched
+occupancy grids — ``dmask[S/bm, K/bk]`` (frontier slab holds any finite
+distance) and ``wmask[K/bk, N/bn]`` (weight tile holds any live edge) —
+and a ``pl.when`` guard skips the broadcast-min for (slab, tile) pairs
+whose product is all-+inf, i.e. the semiring identity.  Output-tile init
+still runs at k == 0, so a fully skipped output tile is +inf, exactly what
+the dense kernel computes for it.
 """
 from __future__ import annotations
 
@@ -24,71 +26,50 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from .backend import INTERPRET, check_blocks
+from .backend import semiring_call
 
 DEFAULT_BM = 128
 DEFAULT_BN = 128
-DEFAULT_BK = 16
+DEFAULT_BK = 128
+SUB = 8  # k rows per broadcast-min step inside a block
 
 
-def _kernel(d_ref, w_ref, o_ref):
+def _body(d_ref, w_ref, o_ref, live, nk: int):
+    del nk
     k = pl.program_id(2)
 
     @pl.when(k == 0)
     def _init():
         o_ref[...] = jnp.full_like(o_ref, jnp.inf)
 
-    d = d_ref[...]          # (bm, bk)
-    w = w_ref[...]          # (bk, bn)
-    cand = jnp.min(d[:, :, None] + w[None, :, :], axis=1)
-    o_ref[...] = jnp.minimum(o_ref[...], cand)
-
-
-def _masked_kernel(dm_ref, wm_ref, d_ref, w_ref, o_ref):
-    k = pl.program_id(2)
-
-    @pl.when(k == 0)
-    def _init():
-        o_ref[...] = jnp.full_like(o_ref, jnp.inf)
-
-    @pl.when((dm_ref[0, 0] > 0) & (wm_ref[0, 0] > 0))
+    @pl.when(live)
     def _compute():
-        d = d_ref[...]
-        w = w_ref[...]
-        cand = jnp.min(d[:, :, None] + w[None, :, :], axis=1)
-        o_ref[...] = jnp.minimum(o_ref[...], cand)
+        bk = d_ref.shape[1]
+        sub = min(SUB, bk)
+        acc = o_ref[...]
+        for c in range(0, bk, sub):
+            d = d_ref[:, c:c + sub]          # (bm, sub)
+            w = w_ref[c:c + sub, :]          # (sub, bn)
+            acc = jnp.minimum(acc, jnp.min(d[:, :, None] + w[None, :, :],
+                                           axis=1))
+        o_ref[...] = acc
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
 def minplus_mm(d: jax.Array, w: jax.Array, *, bm: int = DEFAULT_BM,
                bn: int = DEFAULT_BN, bk: int = DEFAULT_BK,
-               interpret: bool = INTERPRET) -> jax.Array:
+               interpret: bool | None = None) -> jax.Array:
     """d: [S, V] f32; w: [V, V'] f32 -> [S, V'] f32 (min-plus product)."""
-    s, kdim = d.shape
-    _, n = w.shape
-    bm, bn, bk = min(bm, s), min(bn, n), min(bk, kdim)
-    check_blocks("minplus_mm", s, kdim, n, bm, bk, bn)
-    grid = (s // bm, n // bn, kdim // bk)
-    return pl.pallas_call(
-        _kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((s, n), jnp.float32),
-        interpret=interpret,
-    )(d, w)
+    return semiring_call("minplus_mm", _body, d, w, None, None, bm=bm,
+                         bn=bn, bk=bk, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
 def minplus_mm_masked(d: jax.Array, w: jax.Array, dmask: jax.Array,
                       wmask: jax.Array, *, bm: int = DEFAULT_BM,
                       bn: int = DEFAULT_BN, bk: int = DEFAULT_BK,
-                      interpret: bool = INTERPRET) -> jax.Array:
+                      interpret: bool | None = None) -> jax.Array:
     """Tile-skipping min-plus product.
 
     ``dmask``: int32 [S/bm, K/bk] — nonzero iff the d slab has a finite
@@ -97,28 +78,5 @@ def minplus_mm_masked(d: jax.Array, w: jax.Array, dmask: jax.Array,
     semiring identity); callers derive both from the tile occupancy index
     (``repro.core.tiles``) or directly from the operands.
     """
-    s, kdim = d.shape
-    _, n = w.shape
-    bm, bn, bk = min(bm, s), min(bn, n), min(bk, kdim)
-    check_blocks("minplus_mm", s, kdim, n, bm, bk, bn)
-    grid = (s // bm, n // bn, kdim // bk)
-    if dmask.shape != (grid[0], grid[2]) or wmask.shape != (grid[2], grid[1]):
-        raise ValueError(
-            f"minplus_mm_masked: mask shapes {dmask.shape}/{wmask.shape} do "
-            f"not match the block grid ({grid[0]}, {grid[2]})/"
-            f"({grid[2]}, {grid[1]})")
-    return pl.pallas_call(
-        _masked_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i, j, k: (i, k),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1), lambda i, j, k: (k, j),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((s, n), jnp.float32),
-        interpret=interpret,
-    )(dmask.astype(jnp.int32), wmask.astype(jnp.int32), d, w)
+    return semiring_call("minplus_mm", _body, d, w, dmask, wmask, bm=bm,
+                         bn=bn, bk=bk, interpret=interpret)

@@ -1,8 +1,8 @@
 """jit'd public wrappers around the Pallas kernels (padding + dispatch).
 
-On a real TPU these run compiled (``interpret=False``); in this CPU container
-they execute the kernel bodies in interpret mode, validated against
-``ref.py`` in ``tests/test_kernels.py``.
+On a TPU these run compiled; on every other backend they execute the
+kernel bodies in interpret mode (``backend.interpret_mode``), validated
+against ``ref.py`` in ``tests/test_kernels.py``.
 
 Each semiring wrapper optionally takes ``amask``, the tile-occupancy grid of
 the right-hand (adjacency/weight) operand at ``tile`` granularity — see
@@ -24,7 +24,7 @@ from . import bool_mm as _bool
 from . import count_mm as _count
 from . import minplus_mm as _minplus
 from . import flash_attention as _flash
-from .backend import INTERPRET, check_amask  # noqa: F401  (INTERPRET re-exported)
+from .backend import check_amask
 
 
 def _pad2(x, bm, bn, value=0.0):
@@ -81,20 +81,19 @@ def bool_mm(f: jax.Array, a: jax.Array, bm: int = 128, bn: int = 128,
     fp, (s, _) = _pad2(f.astype(jnp.float32), bm, bk)
     ap, (_, n) = _pad2(a.astype(jnp.float32), bk, bn)
     if amask is None:
-        out = _bool.bool_mm(fp, ap, bm=bm, bn=bn, bk=bk, interpret=INTERPRET)
+        out = _bool.bool_mm(fp, ap, bm=bm, bn=bn, bk=bk)
     else:
         check_amask("bool_mm", amask.shape, a.shape[0], a.shape[1], tile)
         nbk, nbn = fp.shape[1] // bk, ap.shape[1] // bn
         fmask = _slab_mask(fp, bm, bk, lambda x: x != 0)
         am = _coarsen_mask(amask, tile, bk, nbk, bn, nbn)
-        out = _bool.bool_mm_masked(fp, ap, fmask, am, bm=bm, bn=bn, bk=bk,
-                                   interpret=INTERPRET)
+        out = _bool.bool_mm_masked(fp, ap, fmask, am, bm=bm, bn=bn, bk=bk)
     return out[:s, :n]
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "tile"))
 def minplus_mm(d: jax.Array, w: jax.Array, bm: int = 128, bn: int = 128,
-               bk: int = 16, amask: jax.Array | None = None,
+               bk: int = 128, amask: jax.Array | None = None,
                tile: int = 128) -> jax.Array:
     """Padded tropical matmul; +inf padding is the semiring identity.
 
@@ -104,15 +103,14 @@ def minplus_mm(d: jax.Array, w: jax.Array, bm: int = 128, bn: int = 128,
     dp, (s, _) = _pad2(d, bm, bk, value=jnp.inf)
     wp, (_, n) = _pad2(w, bk, bn, value=jnp.inf)
     if amask is None:
-        out = _minplus.minplus_mm(dp, wp, bm=bm, bn=bn, bk=bk,
-                                  interpret=INTERPRET)
+        out = _minplus.minplus_mm(dp, wp, bm=bm, bn=bn, bk=bk)
     else:
         check_amask("minplus_mm", amask.shape, w.shape[0], w.shape[1], tile)
         nbk, nbn = dp.shape[1] // bk, wp.shape[1] // bn
         dmask = _slab_mask(dp, bm, bk, jnp.isfinite)
         am = _coarsen_mask(amask, tile, bk, nbk, bn, nbn)
         out = _minplus.minplus_mm_masked(dp, wp, dmask, am, bm=bm, bn=bn,
-                                         bk=bk, interpret=INTERPRET)
+                                         bk=bk)
     return out[:s, :n]
 
 
@@ -127,15 +125,13 @@ def count_mm(s: jax.Array, a: jax.Array, bm: int = 128, bn: int = 128,
     sp, (m, _) = _pad2(s.astype(jnp.float32), bm, bk)
     ap, (_, n) = _pad2(a.astype(jnp.float32), bk, bn)
     if amask is None:
-        out = _count.count_mm(sp, ap, bm=bm, bn=bn, bk=bk,
-                              interpret=INTERPRET)
+        out = _count.count_mm(sp, ap, bm=bm, bn=bn, bk=bk)
     else:
         check_amask("count_mm", amask.shape, a.shape[0], a.shape[1], tile)
         nbk, nbn = sp.shape[1] // bk, ap.shape[1] // bn
         smask = _slab_mask(sp, bm, bk, lambda x: x != 0)
         am = _coarsen_mask(amask, tile, bk, nbk, bn, nbn)
-        out = _count.count_mm_masked(sp, ap, smask, am, bm=bm, bn=bn, bk=bk,
-                                     interpret=INTERPRET)
+        out = _count.count_mm_masked(sp, ap, smask, am, bm=bm, bn=bn, bk=bk)
     return out[:m, :n]
 
 
@@ -144,4 +140,4 @@ def flash_attention(q, k, v, causal: bool = True, sm_scale=None, window=None,
     """Causal GQA flash attention; q [B,Hq,S,D], kv [B,Hkv,S,D]."""
     return _flash.flash_attention(
         q, k, v, causal=causal, sm_scale=sm_scale, window=window,
-        bq=bq, bk=bk, interpret=INTERPRET)
+        bq=bq, bk=bk)

@@ -3,7 +3,7 @@
 ``AsyncGraphService`` wraps a :class:`repro.engine.service.GraphService`
 (or the sharded service) with concurrent admission: queries pin a ring
 version at arrival and resolve as Futures; a dispatcher batches
-compatible queries (same kind, same pinned version) into single vmapped
+compatible queries (same kind, same pinned version) into single
 compiled calls; updates commit through the (thread-safe) scheduler
 without ever blocking in-flight reads on older versions.  See
 ``serve.async_service`` for the admission → pin → batch → dispatch

@@ -20,7 +20,7 @@ lifecycle **admission → pin → batch → dispatch**:
     unchanged / delta / full rungs with the sequential ladder's own
     gates (``serve.batch.classify_local``).
   * **dispatch**: each rung that has lanes runs as ONE compiled call —
-    ``jax.vmap`` over the stacked source axis (full) or stacked
+    one program mapped over the stacked source axis (full) or stacked
     ``(prior, dirty, src)`` lanes (delta) — then per-request results are
     sliced out, cached, counted, traced, and the futures resolved.  A
     dispatch failure (including the ``serve.dispatch`` fault point)
@@ -123,7 +123,7 @@ class AsyncGraphService:
         self._inflight_lock = threading.Lock()
         self._drained = threading.Condition(self._inflight_lock)
         self.stats = ServeStats()
-        #: local services get the vmapped compatible-query fast path;
+        #: local services get the batched compatible-query fast path;
         #: anything else (the sharded service) batches by dedup.
         self._local = isinstance(service, GraphService)
 
@@ -331,7 +331,7 @@ class AsyncGraphService:
                     self._fallback(req)
 
     def _dispatch_local(self, kind: str, version: int, entry, reqs):
-        """The vmapped fast path (local service): classify, batch, slice."""
+        """The batched fast path (local service): classify, batch, slice."""
         svc = self.service
         tel = self._telemetry()
         state = entry.state

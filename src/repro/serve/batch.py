@@ -3,21 +3,23 @@
 The dispatcher groups admitted requests by ``(kind, version)`` and this
 module turns each group into at most two compiled calls:
 
-  * the **full** rung is ``jax.vmap`` of the single-source query
-    (``queries.bfs`` / ``sssp`` / ``bc_dependencies``) over the stacked
-    source axis — N concurrent BFS queries at version ``v`` cost one
-    compiled program instead of N dispatches;
-  * the **delta** rung is ``jax.vmap`` of the engine's delta kernels
-    (``delta_bfs`` / ``delta_sssp`` / ``_delta_bc_at_cut``) over stacked
+  * the **full** rung maps the single-source query (``queries.bfs`` /
+    ``sssp`` / ``bc_dependencies``) over the stacked source axis — N
+    concurrent BFS queries at version ``v`` cost one compiled program
+    instead of N dispatches;
+  * the **delta** rung maps the engine's delta kernels (``delta_bfs`` /
+    ``delta_sssp`` / ``_delta_bc_at_cut``) over stacked
     ``(prior, dirty, src)`` lanes — each lane carries its own prior and
     its own accumulated dirty mask, so requests cached at *different*
     earlier versions still share the dispatch.
 
-Per-lane answers are bit-identical to the sequential single-source
-calls: ``jax.vmap`` batches ``lax.while_loop`` by running the body while
-*any* lane is active and ``select``-ing each finished lane's carry
-unchanged, so a lane that converged early keeps exactly the value the
-unbatched loop would have produced.  The concurrent differential suite
+The lanes run one after another inside the program (``lax.map``), not
+side by side (``jax.vmap``): the vmapped gathers over the ``ecap``-long
+edge table put the lane axis minor, and a TPU tile pads a minor axis of
+4 lanes to 128 — at Graph500 scale 20 the vmapped BFS rung needed
+16.8 GB of a 15.75 GB chip, the mapped one 0.7 GB.  Per-lane answers are
+bit-identical to the sequential single-source calls, since each lane
+runs exactly that call.  The concurrent differential suite
 (`tests/stream_differential.py`) holds this as its oracle.
 
 Classification (which rung a request rides) reuses the ladder's own
@@ -27,7 +29,8 @@ exactly the same evidence as ``engine.incremental``'s sequential one.
 
 Lane stacks are padded up to the next power of two (replicating lane 0,
 whose extra output rows are discarded) so the number of compiled batch
-variants stays logarithmic in ``max_batch`` instead of linear.
+variants stays logarithmic in ``max_batch`` instead of linear; each
+padding lane runs lane 0's query once more.
 """
 from __future__ import annotations
 
@@ -36,6 +39,7 @@ from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from repro.core import queries
 from repro.engine.incremental import _delta_bc_at_cut, _dirty_stats, \
@@ -43,20 +47,22 @@ from repro.engine.incremental import _delta_bc_at_cut, _dirty_stats, \
 
 __all__ = ["Lane", "classify_local", "dispatch_local_group", "pad_pow2"]
 
-#: vmapped full rungs: state broadcast, source axis stacked.
-_VFULL = {
-    "bfs": jax.jit(jax.vmap(queries.bfs, in_axes=(None, 0))),
-    "sssp": jax.jit(jax.vmap(queries.sssp, in_axes=(None, 0))),
-    "bc": jax.jit(jax.vmap(queries.bc_dependencies, in_axes=(None, 0))),
-}
 
-#: vmapped delta rungs: state broadcast; prior / dirty-or-cut / source
-#: stacked per lane.
-_VDELTA = {
-    "bfs": jax.jit(jax.vmap(delta_bfs, in_axes=(None, 0, 0, 0))),
-    "sssp": jax.jit(jax.vmap(delta_sssp, in_axes=(None, 0, 0, 0))),
-    "bc": jax.jit(jax.vmap(_delta_bc_at_cut, in_axes=(None, 0, 0, 0))),
-}
+def _lanes(query):
+    """``query(state, *lane_args)`` mapped over stacked lanes in one
+    compiled program; the state is shared by every lane."""
+    return jax.jit(lambda state, *lanes: lax.map(
+        lambda lane: query(state, *lane), lanes))
+
+
+#: full rungs: state shared, source axis stacked.
+_VFULL = {"bfs": _lanes(queries.bfs), "sssp": _lanes(queries.sssp),
+          "bc": _lanes(queries.bc_dependencies)}
+
+#: delta rungs: state shared; prior / dirty-or-cut / source stacked per
+#: lane.
+_VDELTA = {"bfs": _lanes(delta_bfs), "sssp": _lanes(delta_sssp),
+           "bc": _lanes(_delta_bc_at_cut)}
 
 #: reached-region mask of a cached local result, per kind (the unchanged
 #: test: dirty ∩ reached == ∅ ⇒ the cached answer stands).

@@ -73,7 +73,6 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import semiring
@@ -376,7 +375,7 @@ def _ring_mms(a_local, occ_local, *, ax, tile, use_kernel):
         ``rows(b)`` — partials ASSEMBLE by column block, each an intact
         dot against the transposed band (occupancy grid transposed too).
 
-    Collective bytes per rotation: ``band x Vp x 4`` (f32 weights band)
+    Collective bytes per rotation: ``band x Vp`` (int8 {0,1} band)
     ``+ rows x nt x 4`` (int32 occupancy band) = O(Vp^2/n) — the figure
     the collective-byte regression test pins against the compiled HLO.
 
@@ -442,7 +441,10 @@ def _ring_sync(ax):
 
 def _bc_ring_prep(w_local, occ_local, alive, ax, tile, use_kernel):
     alivep, _, edge = _band_views(w_local, alive, ax)
-    fwd_mm, bwd_mm = _ring_mms(edge.astype(jnp.float32), occ_local,
+    # int8 holds {0,1} exactly and quarters the band the ring keeps,
+    # carries and permutes: at vcap 65536 on four 16 GB chips, f32 bands
+    # (held, in flight, and the weights band) needed 16.0 GB per chip.
+    fwd_mm, bwd_mm = _ring_mms(edge.astype(jnp.int8), occ_local,
                                ax=ax, tile=tile, use_kernel=use_kernel)
     return alivep, fwd_mm, bwd_mm
 
@@ -560,11 +562,11 @@ def query_fn(mesh: Mesh, kind: str, tile: int, use_kernel: bool = False,
     else:
         raise ValueError(f"unknown query kind {kind!r}; "
                          f"supported kinds: {', '.join(_KINDS)}")
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(vspec, vspec, rspec, rspec, src_spec, rspec) + extra_specs,
         out_specs=out_specs,
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(fn)
 

@@ -32,7 +32,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.graph_state import INF, GraphState
@@ -235,11 +234,11 @@ def _rows_refresh_fn(mesh: Mesh, tile: int, width: int, nrows: int):
         return lax.fori_loop(0, nrows, write, (w_local, occ_local))
 
     vspec, sspec = P(_axis(mesh), None), P()
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(vspec, vspec, sspec, sspec, sspec, sspec, sspec, sspec),
         out_specs=(vspec, vspec),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(fn, donate_argnums=(0, 1))
 

@@ -1,8 +1,21 @@
+import importlib.util
 import os
 import subprocess
 import sys
 
 sys.path.insert(0, os.path.dirname(__file__))
+
+CHIP_SMOKE = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..",
+                          "chip_smoke.py")
+
+
+def load_chip_smoke():
+    """The repo-root ``chip_smoke.py`` script, imported as a module (its
+    phase functions and constants; its entry point stays unrun)."""
+    spec = importlib.util.spec_from_file_location("chip_smoke", CHIP_SMOKE)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def run_multidevice(script: str, n_devices: int = 4,

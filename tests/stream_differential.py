@@ -476,7 +476,7 @@ def run_concurrent_differential(seed: int, *, n: int = 24, chunks: int = 10,
         chunk prefix to that version and the answer must match it
         semantically AND be bit-equal (``results_equal``) to a fresh
         sequential full collect on the reconstructed snapshot — the
-        vmap/batched-dispatch bit-identity claim, enforced per reply;
+        batched-dispatch bit-identity claim, enforced per reply;
       * chunk boundaries equal commit boundaries by construction (each
         chunk is exactly ``batch_size`` ops, auto-committed), so the
         state at version ``v`` is reproducible as ``apply_ops`` over the

@@ -192,12 +192,14 @@ def test_raw_kernels_reject_truncating_shapes(raw):
 
 def test_raw_kernels_default_interpret_from_backend():
     """The raw kernels must not hardcode interpret=True: the default comes
-    from backend detection (interpret off on real TPU)."""
+    from backend detection at trace time (interpret off on real TPU)."""
     import inspect
     from repro.kernels import backend
     for fn in (raw_bool_mm, raw_minplus_mm, raw_count_mm):
         sig = inspect.signature(fn.__wrapped__)
-        assert sig.parameters["interpret"].default == backend.INTERPRET
+        assert sig.parameters["interpret"].default is None
+    assert backend.resolve_interpret(None) == (jax.default_backend() != "tpu")
+    assert backend.resolve_interpret(False) is False
 
 
 @pytest.mark.parametrize("b,hq,hkv,sq,skv,d", [

@@ -307,7 +307,7 @@ def test_pad_pow2():
 
 def test_batched_full_dispatch_bit_identical():
     """A burst of same-kind queries at one version runs as ONE compiled
-    vmapped dispatch whose per-lane answers are bit-equal to the
+    batched dispatch whose per-lane answers are bit-equal to the
     sequential single-source collects."""
     rng = np.random.default_rng(8)
     g0 = _seed_graph(rng)
@@ -333,7 +333,7 @@ def test_batched_full_dispatch_bit_identical():
 
 def test_batched_delta_rung_bit_identical():
     """Cached priors + a small committed churn: the dispatcher batches
-    the delta lanes (one vmapped delta kernel call) and each lane equals
+    the delta lanes (one batched delta kernel call) and each lane equals
     the sequential full collect on the new snapshot."""
     g0 = _path_graph()
     tel = Telemetry(block=False)

@@ -655,11 +655,11 @@ assert S * vp <= c["all-reduce"] <= S * vp + slack, c          # int8 pmax
 c = coll("sssp")
 assert 4 * S * vp <= c["all-reduce"] <= 4 * S * vp + slack, c  # f32 min-merge
 
-# ring: one rotation = the shard's own band (f32 weights + int32 occ grid)
-# = O(Vp^2/n) bytes; the compiled program carries exactly TWO rotation
-# sites (forward loop, backward loop) per sweep
-per_rot = band * vp * 4 + rows * nt * 4
-assert per_rot == 4 * vp * vp // 4 + 4 * nt * nt // 4
+# ring: one rotation = the shard's own band (int8 {0,1} adjacency + int32
+# occ grid) = O(Vp^2/n) bytes; the compiled program carries exactly TWO
+# rotation sites (forward loop, backward loop) per sweep
+per_rot = band * vp + rows * nt * 4
+assert per_rot == vp * vp // 4 + 4 * nt * nt // 4
 for kind, chunks in (("bc_ring", 1),):
     c = coll(kind)
     assert c["collective-permute"] == 2 * chunks * per_rot, (kind, c)
