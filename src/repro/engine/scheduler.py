@@ -57,6 +57,12 @@ fills a batch carries out its auto-commit while holding it.  Queries
 never take this lock — in-flight reads on pinned ring versions overlap
 every commit; the only cross-structure touch point is the version ring,
 which has its own lock.
+
+Spans (``repro.obs.trace.maybe_span``; profiler annotations even with no
+telemetry): ``lock_wait`` from a submit's entry until it holds the lock,
+and per commit ``commit`` with ``apply`` (the ``apply_batch`` launch and
+its overflow check) and ``ring_commit`` (the dirty set and the append)
+inside it.
 """
 from __future__ import annotations
 
@@ -123,7 +129,9 @@ class StreamScheduler:
         """
         if op[0] not in _VERTEX_OPS and op[0] not in _EDGE_OPS:
             raise ValueError(f"scheduler accepts mutations only, got {op!r}")
-        with self._lock:
+        with maybe_span(self._tracer(), "lock_wait"):
+            self._lock.acquire()
+        try:
             seq = self.stats.ops_submitted
             if self.journal is not None:
                 self.journal.append_op(seq, op)
@@ -132,6 +140,8 @@ class StreamScheduler:
             if self.auto_commit:
                 self._commit_ready()
             return seq
+        finally:
+            self._lock.release()
 
     def submit_many(self, ops: Sequence[Tuple]) -> List[int]:
         return [self.submit(op) for op in ops]
@@ -169,10 +179,13 @@ class StreamScheduler:
                 out.append(op)
         return out
 
+    def _tracer(self):
+        return self.telemetry.tracer if self.telemetry is not None else None
+
     def _commit_chunk(self, chunk: List[Tuple]) -> RingEntry:
         n_raw = len(chunk)
         ops = self._coalesce_chunk(list(chunk))
-        tracer = self.telemetry.tracer if self.telemetry is not None else None
+        tracer = self._tracer()
         mon = self.monitor
         stragglers0 = mon.stragglers if mon is not None else 0
         try:
@@ -181,10 +194,12 @@ class StreamScheduler:
                 if mon is not None:
                     mon.start()
                 inject(P_SCHED_APPLY)
-                state, _ = apply_ops(self.ring.latest.state, ops,
-                                     batch_size=self.batch_size)
+                with maybe_span(tracer, "apply"):
+                    state, _ = apply_ops(self.ring.latest.state, ops,
+                                         batch_size=self.batch_size)
                 inject(P_SCHED_RING_COMMIT)
-                entry = self.ring.commit(state)
+                with maybe_span(tracer, "ring_commit"):
+                    entry = self.ring.commit(state)
                 if mon is not None:
                     mon.stop(entry.version)
                     if mon.stragglers > stragglers0:

@@ -88,8 +88,7 @@ _FULL = {"bfs": queries.bfs, "sssp": queries.sssp,
          "bc": queries.bc_dependencies}
 
 #: per-query cost scratch template (reset at every traced query() entry).
-_QUERY_COST_ZERO = {"coll_bytes": 0, "temp_bytes": 0, "flops": 0.0,
-                    "device_us": 0.0}
+_QUERY_COST_ZERO = {"coll_bytes": 0, "temp_bytes": 0, "flops": 0.0}
 
 #: static delta-vs-full crossover per query kind.  BFS/SSSP deltas are
 #: frontier-local (cost tracks the dirty region), so a generous 25% bound
@@ -464,18 +463,13 @@ class BaseGraphService:
             self._query_dirty_frac = float(frac)
 
     def _traced_collect(self, kind: str, srcs, key, ladder: bool = True):
-        """``_collect`` wrapped in a child span when tracing is on; the
-        device timer blocks the fresh result to attribute its dispatch
-        gap (≈0 for an unchanged cache hit — nothing was dispatched)."""
+        """``_collect`` wrapped in a child span when tracing is on."""
         tel = self.telemetry
         if tel is None:
             return self._collect(kind, srcs, key, ladder=ladder)
         with tel.tracer.span("collect", kind=kind) as sp:
             entry, res, qmode = self._collect(kind, srcs, key, ladder=ladder)
-            dev = tel.profiler.measure(res, name=f"collect:{kind}")
-            self._query_cost["device_us"] += dev
-            sp.set(version=entry.version, mode=qmode,
-                   device_us=round(dev, 1))
+            sp.set(version=entry.version, mode=qmode)
         return entry, res, qmode
 
     # ------------------------------ queries ------------------------------
@@ -524,7 +518,6 @@ class BaseGraphService:
                    cn_interrupts=reply.scan.interrupting_updates,
                    validated=reply.validated,
                    block_us=round(block_us, 1),
-                   device_us=round(self._query_cost["device_us"], 1),
                    coll_bytes=self._query_cost["coll_bytes"],
                    temp_bytes=self._query_cost["temp_bytes"],
                    flops=self._query_cost["flops"],
@@ -534,10 +527,6 @@ class BaseGraphService:
         tel.registry.histogram(
             "query_wall_us", service=self._service_name, kind=kind,
             mode=reply.mode).observe(sp.wall_us)
-        if self._query_cost["device_us"] > 0:
-            tel.registry.histogram(
-                "query_device_us", service=self._service_name, kind=kind,
-                mode=reply.mode).observe(self._query_cost["device_us"])
         # Feed the controller after the span closed so any resulting
         # threshold_adjust span is a sibling, not a child, of the query.
         if self.adaptive is not None and not reply.degraded:

@@ -10,16 +10,15 @@ measurement substrate the serving/ingest work is judged against):
   * :mod:`repro.obs.trace` — span-based tracing with contextvar nesting
     and size-rotated JSONL export; every ``query()`` through either
     service emits a record carrying kind / ring version / ladder mode /
-    wall time / device time / collective bytes, with child spans for
-    scheduler commits, tile refresh, and each collect of the PG-Cn loop;
+    wall time / collective bytes, with child spans for scheduler
+    commits, tile refresh, and each collect of the PG-Cn loop; every
+    span is also a ``jax.profiler.TraceAnnotation`` (``repro.<name>``),
+    traced or not, so a profiler session sees the served path's phases
+    on the device trace's clock, where device time is read;
   * :mod:`repro.obs.hlo` — compiled-program cost accounting
     (``cost_analysis`` / ``memory_analysis`` / HLO collective-byte
     parsing) cached per program signature and attributed to every
     query — sharded *and* local since PR 8;
-  * :mod:`repro.obs.profile` — per-span device-time attribution
-    (dispatch-gap ``block_until_ready`` deltas, ``jax.profiler``
-    annotations when a profiler session is live) behind a null-object
-    default;
   * :mod:`repro.obs.expo` — OpenMetrics exposition of the registry,
     served live (:meth:`Telemetry.serve`) or one-shot
     (``python -m repro.obs.expo``), so scrapes and ``BENCH_*.json``
@@ -60,26 +59,18 @@ from .metrics import (  # noqa: F401
     ModeCounters,
     quantile,
 )
-from .profile import DeviceTimer, NullDeviceTimer  # noqa: F401
 from .trace import TRACE_SCHEMA, Span, Tracer, annotate, current_span, maybe_span  # noqa: F401
 
 
 @dataclass
 class Telemetry:
-    """The bundle a service consumes: registry + tracer + accountant +
-    device timer.
+    """The bundle a service consumes: registry + tracer + accountant.
 
     ``block``: when True (default) a traced query blocks its result before
     the span closes, so the histogram / trace wall times are end-to-end
     device latencies (what a serving benchmark quotes as p50/p99), not
     dispatch times.  Callers that pipeline async dispatches can turn it
     off and keep tracing.
-
-    ``profiler``: the device-time attributor (``repro.obs.profile``).
-    The default :class:`DeviceTimer` blocks each collect's result to
-    measure its dispatch gap — every query span then carries
-    ``device_us``; :class:`NullDeviceTimer` (``make(profile=False)``)
-    reports 0.0 without synchronizing.
     """
 
     registry: MetricsRegistry = field(default_factory=MetricsRegistry)
@@ -87,25 +78,22 @@ class Telemetry:
     accountant: Optional[HLOCostAccountant] = field(
         default_factory=HLOCostAccountant)
     block: bool = True
-    profiler: object = field(default_factory=DeviceTimer)
 
     @classmethod
     def make(cls, trace_path: Optional[str] = None, *, block: bool = True,
-             hlo: bool = True, profile: bool = True,
+             hlo: bool = True,
              trace_max_bytes: Optional[int] = None,
              trace_keep: int = 3) -> "Telemetry":
         """One-call construction: in-memory by default, JSONL-sinking when
         ``trace_path`` is given (size-rotated at ``trace_max_bytes``,
         keeping ``trace_keep`` rotated files); ``hlo=False`` skips cost
         accounting (no extra compiles — e.g. compile-latency-sensitive
-        tests); ``profile=False`` skips device-time attribution (no
-        per-collect synchronization)."""
+        tests)."""
         return cls(registry=MetricsRegistry(),
                    tracer=Tracer(path=trace_path, max_bytes=trace_max_bytes,
                                  keep=trace_keep),
                    accountant=HLOCostAccountant() if hlo else None,
-                   block=block,
-                   profiler=DeviceTimer() if profile else NullDeviceTimer())
+                   block=block)
 
     def serve(self, port: int = 0, *, host: str = "127.0.0.1",
               journal=None):

@@ -29,9 +29,9 @@ One-shot CLI (the offline twin of a live scrape)::
     PYTHONPATH=src python -m repro.obs.expo TRACE.jsonl [...] \
         [--check] [--serve PORT]
 
-rebuilds a registry from trace JSONL file(s) — ``query_wall_us`` /
-``query_device_us`` histograms and the per-service query/degraded/error
-counters — and prints (or serves) its exposition.
+rebuilds a registry from trace JSONL file(s) — the ``query_wall_us``
+histograms and the per-service query/degraded/error counters — and
+prints (or serves) its exposition.
 
 :func:`validate_openmetrics` is the line-format checker CI scrapes
 through: TYPE/HELP present per family, counter samples suffixed
@@ -71,8 +71,6 @@ _HELP = {
     "service_degraded": "Stale-but-correct degraded replies served.",
     "service_retries": "Demoted re-collect attempts the resilience ladder ran.",
     "query_wall_us": "End-to-end query wall time in microseconds.",
-    "query_device_us": "Per-query device-side time in microseconds "
-                       "(block_until_ready deltas summed over collects).",
     "adaptive_dirty_threshold": "Current per-kind delta-vs-full crossover "
                                 "threshold the ladder consults.",
     "adaptive_adjustments": "Threshold adjustments the controller applied.",
@@ -354,8 +352,8 @@ class ExpoServer:
 def registry_from_trace(records: list) -> MetricsRegistry:
     """Rebuild the scrape-facing registry a traced run would have fed.
 
-    Query records become ``query_wall_us`` / ``query_device_us``
-    histogram samples and per-service ``service_queries`` /
+    Query records become ``query_wall_us`` histogram samples and
+    per-service ``service_queries`` /
     ``service_degraded`` / ``service_errors`` counters — the same names,
     labels and quantile math as the live service, so the one-shot CLI and
     a live scrape expose identical surfaces.
@@ -371,9 +369,6 @@ def registry_from_trace(records: list) -> MetricsRegistry:
         kind, mode = r.get("kind", "?"), r.get("mode", "?")
         reg.histogram("query_wall_us", service=service, kind=kind,
                       mode=mode).observe(r.get("wall_us", 0.0))
-        if r.get("device_us") is not None:
-            reg.histogram("query_device_us", service=service, kind=kind,
-                          mode=mode).observe(r["device_us"])
         if r.get("degraded"):
             reg.counter("service_degraded", service=service).inc()
         else:

@@ -6,12 +6,12 @@
 Aggregates the ``span == "query"`` records a traced
 ``GraphService``/``ShardedGraphService`` emitted: one row per
 (service, kind, ladder mode) with query counts, wall-time quantiles,
-device-time medians, validated counts, degraded counts, and mean
-HLO-attributed collective bytes.  Multiple trace files (a rotated sink's
+validated counts, degraded counts, and mean HLO-attributed collective
+bytes.  Multiple trace files (a rotated sink's
 ``trace.jsonl.N`` siblings, or per-process traces) are merged and sorted
 by span id before aggregation.  ``--check`` turns the reader into a CI
 gate: every completed query record must carry the full schema
-(kind/version/mode/degraded/wall/device-time/collective-bytes/flops);
+(kind/version/mode/degraded/wall/collective-bytes/flops);
 records that ended in an error (they carry an ``error`` field and no
 version/mode to claim) are exempt from the field check but counted.
 ``--require-modes`` demands a non-empty row per named ladder mode;
@@ -36,7 +36,7 @@ from .trace import TRACE_SCHEMA
 #: fields every completed query trace record must carry (the acceptance
 #: schema); error-terminated records carry ``error`` instead.
 QUERY_FIELDS = ("schema", "span", "wall_us", "kind", "version", "mode",
-                "coll_bytes", "service", "degraded", "device_us", "flops")
+                "coll_bytes", "service", "degraded", "flops")
 
 
 def load(path: str) -> list:
@@ -109,14 +109,12 @@ def summarize(records: list) -> list:
     rows = []
     for (service, kind, mode), rs in sorted(groups.items()):
         walls = [r.get("wall_us", 0.0) for r in rs]
-        devs = [r.get("device_us", 0.0) or 0.0 for r in rs]
         rows.append({
             "service": service, "kind": kind, "mode": mode,
             "queries": len(rs),
             "p50_us": round(quantile(walls, 0.50), 1),
             "p95_us": round(quantile(walls, 0.95), 1),
             "p99_us": round(quantile(walls, 0.99), 1),
-            "device_p50_us": round(quantile(devs, 0.50), 1),
             "validated": sum(bool(r.get("validated")) for r in rs),
             "degraded": sum(bool(r.get("degraded")) for r in rs),
             "errors": sum("error" in r for r in rs),
@@ -128,7 +126,7 @@ def summarize(records: list) -> list:
 
 def render(rows: list) -> str:
     cols = ("service", "kind", "mode", "queries", "p50_us", "p95_us",
-            "p99_us", "device_p50_us", "validated", "degraded", "errors",
+            "p99_us", "validated", "degraded", "errors",
             "coll_bytes_mean")
     widths = {c: max(len(c), *(len(str(r[c])) for r in rows)) if rows
               else len(c) for c in cols}

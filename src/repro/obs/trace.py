@@ -28,6 +28,13 @@ would cross the limit first rotates ``trace.jsonl`` → ``trace.jsonl.1``
 (shifting older rotations up to ``keep``, dropping the oldest) and
 reopens fresh — counted in ``tracer.rotations``.
 
+Every span is also a ``jax.profiler.TraceAnnotation`` named
+``repro.<name>`` (:data:`PROFILER_PREFIX`), whether or not a tracer is
+attached, whenever a profiler session is collecting: it lands on the
+device trace's clock and its scalar attributes travel as the event's
+stats.  With no session an annotation would be inert, so none is built:
+untraced code pays one session check and a ``None`` check per span.
+
 Thread-safety: span *nesting* is already per-thread for free
 (:mod:`contextvars` — each serving thread sees its own current-span
 stack), but id assignment and record emission mutate shared tracer
@@ -42,16 +49,23 @@ import json
 import os
 import threading
 import time
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import IO, Optional
+
+from jax.profiler import TraceAnnotation
 
 from repro.resil.faults import P_OBS_SINK, InjectedFault, inject
 
-__all__ = ["TRACE_SCHEMA", "Span", "Tracer", "annotate", "current_span"]
+__all__ = ["PROFILER_PREFIX", "TRACE_SCHEMA", "Span", "Tracer", "annotate",
+           "current_span", "maybe_span"]
 
 #: bump when the record layout changes; readers reject unknown majors.
-#: 2: query spans additionally carry device_us + flops (PR 8).
-TRACE_SCHEMA = 2
+#: 2: query spans additionally carry device_us + flops.
+#: 3: device_us is gone (device time comes from the profiler's trace).
+TRACE_SCHEMA = 3
+
+#: prefix of every span's profiler annotation (``repro.dispatch``, ...).
+PROFILER_PREFIX = "repro."
 
 _CURRENT: contextvars.ContextVar = contextvars.ContextVar(
     "repro_obs_span", default=None)
@@ -59,6 +73,30 @@ _CURRENT: contextvars.ContextVar = contextvars.ContextVar(
 
 def current_span() -> Optional["Span"]:
     return _CURRENT.get()
+
+
+def _scalars(attrs: dict) -> dict:
+    """The attributes a profiler event can carry as stats."""
+    return {k: v for k, v in attrs.items()
+            if isinstance(v, (bool, int, float, str))}
+
+
+_NO_ANNOTATION = nullcontext()
+
+
+def _annotation(name: str, attrs: dict):
+    """The profiler event of span ``name`` (the only place one is built);
+    with no profiler session collecting, an event would be inert, so none
+    is built."""
+    if not TraceAnnotation.is_enabled():
+        return _NO_ANNOTATION
+    return TraceAnnotation(PROFILER_PREFIX + name, **_scalars(attrs))
+
+
+def _forward(ann: Optional[TraceAnnotation], attrs: dict) -> None:
+    """Late attributes of a span onto its profiler event, if it has one."""
+    if ann is not None:
+        ann.set_metadata(**_scalars(attrs))
 
 
 def annotate(**attrs) -> None:
@@ -71,7 +109,7 @@ def annotate(**attrs) -> None:
 class Span:
     """One open region; becomes a single trace record on exit."""
 
-    __slots__ = ("name", "id", "parent", "attrs", "t0", "wall_us")
+    __slots__ = ("name", "id", "parent", "attrs", "t0", "wall_us", "ann")
 
     def __init__(self, name: str, span_id: int, parent: Optional[int],
                  attrs: dict):
@@ -81,13 +119,16 @@ class Span:
         self.attrs = attrs
         self.t0 = time.perf_counter()
         self.wall_us = 0.0
+        self.ann: Optional[TraceAnnotation] = None
 
     def set(self, **attrs) -> None:
         self.attrs.update(attrs)
+        _forward(self.ann, attrs)
 
     def setdefault(self, **attrs) -> None:
-        for k, v in attrs.items():
-            self.attrs.setdefault(k, v)
+        new = {k: v for k, v in attrs.items() if k not in self.attrs}
+        self.attrs.update(new)
+        _forward(self.ann, new)
 
 
 class Tracer:
@@ -123,7 +164,8 @@ class Tracer:
         sp = Span(name, span_id, getattr(_CURRENT.get(), "id", None), attrs)
         token = _CURRENT.set(sp)
         try:
-            yield sp
+            with _annotation(name, attrs) as sp.ann:
+                yield sp
         finally:
             _CURRENT.reset(token)
             sp.wall_us = (time.perf_counter() - sp.t0) * 1e6
@@ -194,26 +236,33 @@ class Tracer:
 
 @contextmanager
 def maybe_span(tracer: Optional[Tracer], name: str, **attrs):
-    """``tracer.span`` when tracing, a reusable null span otherwise — so
-    instrumented code writes one code path and pays a single ``None``
-    check when telemetry is off."""
+    """``tracer.span`` when tracing, else a span that records nothing and
+    only opens the profiler annotation — so instrumented code writes one
+    code path and, with telemetry off, still shows on a profiler trace."""
     if tracer is None:
-        yield _NULL_SPAN
+        with _annotation(name, attrs) as ann:
+            yield _NULL_SPAN if ann is None else _ProfilerSpan(ann)
     else:
         with tracer.span(name, **attrs) as sp:
             yield sp
 
 
-class _NullSpan:
-    __slots__ = ()
+class _ProfilerSpan:
+    """The span of an untraced region: attributes go to the profiler
+    event alone."""
+
+    __slots__ = ("ann",)
     id = None
     wall_us = 0.0
 
+    def __init__(self, ann: Optional[TraceAnnotation]):
+        self.ann = ann
+
     def set(self, **attrs) -> None:
-        pass
+        _forward(self.ann, attrs)
 
     def setdefault(self, **attrs) -> None:
-        pass
+        _forward(self.ann, attrs)
 
 
-_NULL_SPAN = _NullSpan()
+_NULL_SPAN = _ProfilerSpan(None)

@@ -40,12 +40,20 @@ the then-latest version and says so in ``reply.version``).
 
 Telemetry (when the wrapped service carries it): ``serve_queue_depth``
 gauge, ``serve_batch_size`` histogram (lanes per compiled dispatch),
-``serve_request_us`` histogram (admission -> reply), per-batch
-``dispatch`` spans and per-request ``query`` records, and
+``serve_request_us`` histogram (admission to the future resolved on the
+host, which for a rung answer is before the device finishes it),
+per-batch ``dispatch`` spans and per-request ``query`` records, and
 ``serve_batched_dispatches`` / ``serve_fallbacks`` counters — the
 conservation invariant ``unchanged + delta + full == queries == clean
 query trace records`` holds for batched queries exactly as for
-sequential ones.
+sequential ones.  Inside ``dispatch`` the local path opens ``classify``
+(the group's rung choice), one ``rung`` per compiled rung program
+(``serve.batch``) and ``finish`` (cache stores and resolving); like every
+span these are profiler annotations even with no telemetry attached.
+``ServeStats`` counts, with or without telemetry, the requests the
+dispatcher ``picked`` and their summed admission-to-pick
+``queue_wait_us``, and the ``lanes_run`` and ``pad_lanes`` of the rung
+programs.
 """
 from __future__ import annotations
 
@@ -63,7 +71,7 @@ from repro.obs.trace import maybe_span
 from repro.resil.faults import P_SERVE_DISPATCH, InjectedCrash, \
     InjectedFault, inject
 
-from .batch import classify_local, dispatch_local_group
+from .batch import classify_local, dispatch_local_group, pad_pow2
 
 __all__ = ["AsyncGraphService"]
 
@@ -95,6 +103,10 @@ class ServeStats:
     fallbacks: int = 0               # requests served by the resilient path
     deadline_expired: int = 0
     max_batch_seen: int = 0
+    picked: int = 0                  # requests the dispatcher dequeued
+    queue_wait_us: int = 0           # their admission-to-dequeue time
+    lanes_run: int = 0               # lanes of rung programs (incl. re-runs)
+    pad_lanes: int = 0               # padding lanes pad_pow2 added
     _lock: threading.Lock = field(default_factory=threading.Lock,
                                   repr=False)
 
@@ -267,6 +279,11 @@ class AsyncGraphService:
                     batch.append(self._queue.get_nowait())
                 except queue_mod.Empty:
                     break
+            now = time.perf_counter()
+            with self.stats._lock:
+                self.stats.picked += len(batch)
+                self.stats.queue_wait_us += round(
+                    1e6 * sum(now - req.t_admit for req in batch))
             self._observe_queue_depth()
             try:
                 self._dispatch(batch)
@@ -334,17 +351,25 @@ class AsyncGraphService:
         """The batched fast path (local service): classify, batch, slice."""
         svc = self.service
         tel = self._telemetry()
+        tracer = tel.tracer if tel is not None else None
         state = entry.state
-        for i, req in enumerate(reqs):
-            req.lane = classify_local(svc, kind, req.src, version, state)
-            req.lane.index = i
+        with maybe_span(tracer, "classify", lanes=len(reqs)):
+            for i, req in enumerate(reqs):
+                req.lane = classify_local(svc, kind, req.src, version,
+                                          state)
+                req.lane.index = i
         lanes = [req.lane for req in reqs]
         results, sizes = dispatch_local_group(svc, kind, state, lanes)
         self._note_dispatch(kind, sizes)
-        for req, res in zip(reqs, results):
-            svc._cache_store((kind, req.src), version, res)
-            self._finish(req, res, req.lane.mode, version,
-                         validated=False)
+        with self.stats._lock:
+            self.stats.lanes_run += sum(sizes.values())
+            self.stats.pad_lanes += sum(pad_pow2(n) - n
+                                        for n in sizes.values())
+        with maybe_span(tracer, "finish", lanes=len(reqs)):
+            for req, res in zip(reqs, results):
+                svc._cache_store((kind, req.src), version, res)
+                self._finish(req, res, req.lane.mode, version,
+                             validated=False)
         return sizes
 
     def _dispatch_dedup(self, kind: str, version: int, entry, reqs):

@@ -31,6 +31,11 @@ Lane stacks are padded up to the next power of two (replicating lane 0,
 whose extra output rows are discarded) so the number of compiled batch
 variants stays logarithmic in ``max_batch`` instead of linear; each
 padding lane runs lane 0's query once more.
+
+Each rung program's stacking, padding and launch sit in one ``rung``
+span (kind, rung, lanes, pad): a profiler annotation even untraced, the
+host side that a device trace's ``jit__lambda`` events join to in launch
+order.
 """
 from __future__ import annotations
 
@@ -44,6 +49,7 @@ from jax import lax
 from repro.core import queries
 from repro.engine.incremental import _delta_bc_at_cut, _dirty_stats, \
     delta_bfs, delta_sssp
+from repro.obs.trace import maybe_span
 
 __all__ = ["Lane", "classify_local", "dispatch_local_group", "pad_pow2"]
 
@@ -160,6 +166,8 @@ def dispatch_local_group(service, kind: str, state,
     ``incremental_sssp`` contract) — callers must read ``lane.mode``
     after this returns.
     """
+    tel = service.telemetry
+    tracer = tel.tracer if tel is not None else None
     results: List = [None] * len(lanes)
     sizes: Dict[str, int] = {}
     full_lanes = [ln for ln in lanes if ln.mode == "full"]
@@ -171,18 +179,22 @@ def dispatch_local_group(service, kind: str, state,
     if delta_lanes:
         n = len(delta_lanes)
         pad = pad_pow2(n) - n
-        srcs = jnp.asarray([ln.src for ln in delta_lanes], jnp.int32)
-        if pad:
-            srcs = jnp.concatenate([srcs, jnp.repeat(srcs[:1], pad)])
-        priors = _stack_pad([ln.prior for ln in delta_lanes], pad)
-        if kind == "bc":
-            cuts = jnp.asarray([ln.cut for ln in delta_lanes], jnp.int32)
+        with maybe_span(tracer, "rung", kind=kind, rung="delta", lanes=n,
+                        pad=pad):
+            srcs = jnp.asarray([ln.src for ln in delta_lanes], jnp.int32)
             if pad:
-                cuts = jnp.concatenate([cuts, jnp.repeat(cuts[:1], pad)])
-            out = _VDELTA[kind](state, priors, cuts, srcs)
-        else:
-            dirt = _stack_pad([ln.dirty for ln in delta_lanes], pad)
-            out = _VDELTA[kind](state, priors, dirt, srcs)
+                srcs = jnp.concatenate([srcs, jnp.repeat(srcs[:1], pad)])
+            priors = _stack_pad([ln.prior for ln in delta_lanes], pad)
+            if kind == "bc":
+                cuts = jnp.asarray([ln.cut for ln in delta_lanes],
+                                   jnp.int32)
+                if pad:
+                    cuts = jnp.concatenate(
+                        [cuts, jnp.repeat(cuts[:1], pad)])
+                out = _VDELTA[kind](state, priors, cuts, srcs)
+            else:
+                dirt = _stack_pad([ln.dirty for ln in delta_lanes], pad)
+                out = _VDELTA[kind](state, priors, dirt, srcs)
         per_lane = _unstack(out, n)
         sizes["delta"] = n
         for ln, res in zip(delta_lanes, per_lane):
@@ -197,10 +209,12 @@ def dispatch_local_group(service, kind: str, state,
     if full_lanes:
         n = len(full_lanes)
         pad = pad_pow2(n) - n
-        srcs = jnp.asarray([ln.src for ln in full_lanes], jnp.int32)
-        if pad:
-            srcs = jnp.concatenate([srcs, jnp.repeat(srcs[:1], pad)])
-        out = _VFULL[kind](state, srcs)
+        with maybe_span(tracer, "rung", kind=kind, rung="full", lanes=n,
+                        pad=pad):
+            srcs = jnp.asarray([ln.src for ln in full_lanes], jnp.int32)
+            if pad:
+                srcs = jnp.concatenate([srcs, jnp.repeat(srcs[:1], pad)])
+            out = _VFULL[kind](state, srcs)
         per_lane = _unstack(out, n)
         sizes["full"] = n
         for ln, res in zip(full_lanes, per_lane):

@@ -12,6 +12,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import pytest
 
 from repro.obs import (
     CounterStruct,
@@ -124,13 +125,45 @@ def test_maybe_span_null_path():
     assert sp.id is None
 
 
+@pytest.mark.parametrize("traced", [False, True])
+def test_span_is_a_profiler_annotation(tmp_path, traced):
+    """Traced or not, a span is a ``repro.<name>`` profiler event whose
+    stats carry its scalar attributes, those set late included."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    tr = Tracer() if traced else None
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with maybe_span(tr, "rung", kind="bfs", lanes=3, skip=[1]) as sp:
+            with maybe_span(tr, "inner"):
+                pass
+            sp.set(pad=1, ok=True)
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
+                          "*.xplane.pb"))
+    events = {e.name: dict(e.stats)
+              for plane in ProfileData.from_file(path).planes
+              for line in plane.lines for e in line.events
+              if e.name.startswith("repro.")}
+    assert set(events) == {"repro.rung", "repro.inner"}
+    assert events["repro.rung"] == {"kind": "bfs", "lanes": 3, "pad": 1,
+                                    "ok": True}
+    assert (sp.id is not None) == traced
+    if traced:
+        assert [r["span"] for r in tr.records] == ["inner", "rung"]
+        assert tr.records[1]["skip"] == [1]     # JSONL keeps every attr
+
+
 def test_tracer_jsonl_and_report_gate(tmp_path):
     path = tmp_path / "t.jsonl"
     tr = Tracer(str(path))
     for mode in ("unchanged", "delta", "full"):
         with tr.span("query", service="local", kind="bfs", version=1,
                      mode=mode, coll_bytes=0, degraded=False,
-                     device_us=12.5, flops=100.0):
+                     flops=100.0):
             pass
     tr.close()
     records = report.load(str(path))
@@ -221,9 +254,10 @@ def test_local_service_trace_schema(tmp_path):
 def test_local_service_device_and_flops_attribution(tmp_path):
     """With the accountant on, every local query span carries ``flops``
     from the compiled program that answered it (and zero collective
-    bytes — the local engine has no collectives), and ``device_us`` from
-    the per-collect dispatch-gap measurement.  The unchanged shortcut
-    runs no program, so its span legitimately reports zero flops."""
+    bytes — the local engine has no collectives).  The unchanged shortcut
+    runs no program, so its span legitimately reports zero flops.  Device
+    time is the profiler trace's to give (schema 3): no record carries
+    ``device_us`` and no ``query_device_us`` histogram is fed."""
     from repro.core import PUTE, PUTV, make_graph
     from repro.engine import GraphService
 
@@ -250,12 +284,10 @@ def test_local_service_device_and_flops_attribution(tmp_path):
     assert unchanged["flops"] == 0        # no program dispatched
     for r in qrecs:
         assert r["coll_bytes"] == 0       # local engine: no collectives
-        assert r["device_us"] >= 0
-    assert full["device_us"] > 0          # the full sweep really ran
-    # the device-time histogram only sees queries that dispatched work
-    hists = tel.registry.find("query_device_us", service="local")
-    assert sum(h.count for h in hists) == sum(
-        1 for r in qrecs if r["device_us"] > 0)
+        assert r["schema"] == TRACE_SCHEMA == 3
+        assert "device_us" not in r
+    assert full["wall_us"] > 0            # the full sweep really ran
+    assert tel.registry.find("query_device_us", service="local") == []
 
 
 # ------------------------- metrics edge cases (PR 8) ------------------------
@@ -407,7 +439,8 @@ def test_expo_cli_one_shot(tmp_path, capsys):
     tr.close()
     assert expo.main([str(path), "--check"]) == 0
     out = capsys.readouterr().out
-    assert "query_wall_us" in out and "query_device_us" in out
+    # schema-2 records' device_us no longer rebuilds a histogram
+    assert "query_wall_us" in out and "query_device_us" not in out
     assert 'service_errors_total{service="local"} 1' in out
 
 
@@ -477,18 +510,18 @@ def test_trace_rotation_failure_keeps_stream(tmp_path, monkeypatch):
 
 def test_report_multi_file_and_json_format(tmp_path, capsys):
     """S2: rotated trace siblings merge (sorted by span id), ``--format
-    json`` emits machine-readable rows, and the summary carries the
-    device-time column."""
+    json`` emits machine-readable rows, and the summary carries no
+    device-time column (schema 3)."""
     p1, p2 = tmp_path / "t.jsonl.1", tmp_path / "t.jsonl"
     tr = Tracer(str(p1))
     common = dict(service="local", kind="bfs", version=1, coll_bytes=0,
                   degraded=False, flops=10.0)
-    with tr.span("query", mode="full", device_us=400.0, **common):
+    with tr.span("query", mode="full", **common):
         pass
     tr.close()
     tr2 = Tracer(str(p2))
     tr2._next_id = 50                  # rotated continuation: later ids
-    with tr2.span("query", mode="delta", device_us=40.0, **common):
+    with tr2.span("query", mode="delta", **common):
         pass
     tr2.close()
 
@@ -497,9 +530,8 @@ def test_report_multi_file_and_json_format(tmp_path, capsys):
     assert report.validate(records) == []
     rows = report.summarize(records)
     assert {r["mode"] for r in rows} == {"full", "delta"}
-    by_mode = {r["mode"]: r for r in rows}
-    assert by_mode["full"]["device_p50_us"] == 400.0
-    assert by_mode["delta"]["device_p50_us"] == 40.0
+    assert all("device_p50_us" not in r for r in rows)
+    assert "device_p50_us" not in report.render(rows)
 
     assert report.main([str(p2), str(p1), "--format", "json",
                         "--check"]) == 0
@@ -516,31 +548,12 @@ def test_report_error_span_exemption():
          "service": "local", "kind": "bfs", "error": "Boom"},
         {"schema": TRACE_SCHEMA, "span": "query", "id": 1, "wall_us": 9.0,
          "service": "local", "kind": "bfs", "version": 1, "mode": "full",
-         "coll_bytes": 0, "degraded": False, "device_us": 1.0,
-         "flops": 2.0},
+         "coll_bytes": 0, "degraded": False, "flops": 2.0},
     ]
     assert report.validate(recs) == []
     rows = report.summarize(recs)
     err_row = next(r for r in rows if r["errors"])
     assert err_row["errors"] == 1
-
-
-# --------------------------- device-time profiler ---------------------------
-
-def test_device_timer_measures_and_accumulates():
-    from repro.obs.profile import DeviceTimer, NullDeviceTimer
-
-    t = DeviceTimer()
-    x = jnp.arange(1024.0)
-    y = jnp.dot(x, x)
-    us = t.measure(y, name="dot")
-    assert us >= 0.0 and t.measures == 1 and t.total_us == us
-    t.measure(None, name="empty")          # nothing to block: fine
-    assert t.measures == 2
-
-    n = NullDeviceTimer()
-    assert n.measure(y, name="dot") == 0.0
-    assert not n.blocking and t.blocking
 
 
 # ------------------------- adaptive thresholds ------------------------------

@@ -668,7 +668,7 @@ def test_adaptive_thresholds_ride_the_snapshot(tmp_path):
     from repro.obs import Telemetry
     rng = np.random.default_rng(26)
     g0 = _seed_graph(rng)
-    tel = Telemetry.make(str(tmp_path / "t.jsonl"), hlo=False, profile=False)
+    tel = Telemetry.make(str(tmp_path / "t.jsonl"), hlo=False)
     kw = dict(batch_size=4)
     journal = OpJournal(str(tmp_path / "wal.jsonl"),
                         meta=journal_meta(g0, kw))
@@ -682,8 +682,7 @@ def test_adaptive_thresholds_ride_the_snapshot(tmp_path):
     assert report["version"] == svc.ring.latest.version
     journal.close()
 
-    tel2 = Telemetry.make(str(tmp_path / "t2.jsonl"), hlo=False,
-                          profile=False)
+    tel2 = Telemetry.make(str(tmp_path / "t2.jsonl"), hlo=False)
     rec = recover(str(tmp_path / "wal.jsonl"), batch_size=4,
                   telemetry=tel2, adaptive=True)
     got = rec.adaptive.thresholds()
@@ -768,7 +767,7 @@ def test_breaker_trips_pins_full_and_half_open_restores(tmp_path):
     from repro.obs import Telemetry
     rng = np.random.default_rng(31)
     g0 = _seed_graph(rng)
-    tel = Telemetry.make(str(tmp_path / "t.jsonl"), hlo=False, profile=False)
+    tel = Telemetry.make(str(tmp_path / "t.jsonl"), hlo=False)
     oracle = GraphService(g0, batch_size=4)  # fault-free twin
     svc = GraphService(g0, batch_size=4, telemetry=tel,
                        policy=ResiliencePolicy(max_retries=1),

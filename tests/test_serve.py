@@ -439,3 +439,105 @@ def test_updates_overlap_pinned_reads():
             reply = f.result(timeout=120)
             assert reply.version in (0, 1, 2, 3, 4)
     assert svc.ring.pinned_versions() == []
+
+
+# ------------------------- spans and counters --------------------------------
+
+def _profiled(tmp_path, body):
+    """Run ``body()`` under a profiler session; the ``repro.`` host
+    events it left, as ``(name, stats)`` in start order."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
+                          "*.xplane.pb"))
+    events = [(e.start_ns, e.name[len("repro."):], dict(e.stats))
+              for plane in ProfileData.from_file(path).planes
+              if plane.name.startswith("/host:")
+              for line in plane.lines for e in line.events
+              if e.name.startswith("repro.")]
+    return [(name, stats) for _, name, stats in sorted(events)]
+
+
+def test_served_spans_reach_the_profiler_untraced(tmp_path):
+    """With no telemetry attached (how the benchmark serves), the
+    dispatcher's phases and the commit's parts still land on a profiler
+    trace as ``repro.`` events, their attributes as event stats."""
+    g0 = _path_graph()
+    svc = GraphService(g0, batch_size=2)
+
+    def body():
+        with AsyncGraphService(svc, max_batch=16) as srv:
+            for f in [srv.query_async("bfs", s) for s in (0, 1, 2)]:
+                f.result(timeout=120)
+            srv.submit_many([(PUTE, 5, 7, 1.0), (PUTE, 9, 11, 1.0)])
+            for f in [srv.query_async("bfs", s) for s in (0, 1, 2)]:
+                f.result(timeout=120)
+
+    events = _profiled(tmp_path, body)
+    names = {n for n, _ in events}
+    assert {"dispatch", "classify", "rung", "finish", "lock_wait",
+            "commit", "apply", "ring_commit"} <= names, names
+    rungs = [st for n, st in events if n == "rung"]
+    assert all(st["kind"] == "bfs" and st["pad"] == pad_pow2(st["lanes"])
+               - st["lanes"] for st in rungs)
+    assert {st["rung"] for st in rungs} == {"full", "delta"}
+    commit = next(st for n, st in events if n == "commit")
+    assert commit["batch_ops"] == 2 and commit["version"] == 1  # set late
+    dispatch = next(st for n, st in events if n == "dispatch")
+    assert dispatch["kind"] == "bfs" and dispatch["version"] == 0
+    assert sum(st["lanes"] for n, st in events if n == "classify") == 6
+
+
+def test_serve_counters_lanes_pads_and_queue_wait():
+    """``lanes_run + pad_lanes`` is the padded width summed over the rung
+    programs run, and every admitted request is picked once, its queue
+    wait counted."""
+    rng = np.random.default_rng(13)
+    tel = Telemetry(block=False)
+    svc = GraphService(_seed_graph(rng), batch_size=4, telemetry=tel)
+    with AsyncGraphService(svc, max_batch=16) as srv:
+        for kind in ("bfs", "sssp"):
+            for f in [srv.query_async(kind, s) for s in range(5)]:
+                f.result(timeout=120)
+        srv.submit_many([(PUTE, 1, 2, 3.0)] * 4)
+        for f in [srv.query_async("bfs", s) for s in range(3)]:
+            f.result(timeout=120)
+    st = srv.stats
+    sizes = [n for h in tel.registry.find("serve_batch_size")
+             for n in h.samples]
+    assert st.lanes_run == sum(sizes) > 0
+    assert st.lanes_run + st.pad_lanes == sum(pad_pow2(n) for n in sizes)
+    assert st.picked == st.admitted == 13
+    assert st.queue_wait_us >= 0
+
+
+def test_served_spans_nest_in_trace_records():
+    """With telemetry the same spans become JSONL records: classify,
+    rung and finish nest under their dispatch, apply and ring_commit
+    under their commit."""
+    g0 = _path_graph()
+    tel = Telemetry(block=False, accountant=None)
+    svc = GraphService(g0, batch_size=2, telemetry=tel)
+    with AsyncGraphService(svc, max_batch=16) as srv:
+        for f in [srv.query_async("bfs", s) for s in (0, 1)]:
+            f.result(timeout=120)
+        srv.submit_many([(PUTE, 5, 7, 1.0), (PUTE, 9, 11, 1.0)])
+    recs = tel.tracer.records
+    by_id = {r["id"]: r for r in recs}
+    parent = {r["span"]: by_id[r["parent"]]["span"] for r in recs
+              if r["parent"] is not None and r["span"] != "query"}
+    assert parent == {"classify": "dispatch", "rung": "dispatch",
+                      "finish": "dispatch", "apply": "commit",
+                      "ring_commit": "commit"}
+    rung = next(r for r in recs if r["span"] == "rung")
+    assert (rung["kind"], rung["rung"], rung["lanes"], rung["pad"]) == \
+        ("bfs", "full", 2, 0)
+    assert sum(r["span"] == "lock_wait" for r in recs) == 2
