@@ -20,13 +20,25 @@ predecessor in the (key, index)-sorted order -- no sequential scan needed.
 ``ecnt[u]`` is bumped once per successful mutation of u's out-edge list
 (PutE add / PutE weight-replace / RemE / incident-edge invalidation by RemV),
 mirroring the paper's FetchAndAdd sites.
+
+A commit writes one new version of the sorted edge table with streaming
+passes only: the appends are merged in by a shift of the table
+(``repro.kernels.shift_merge``) and RemV's incident edges are found by
+comparing every slot with the removed ids.  Scatters and gathers are
+bounded by the batch (or the invalidation loop's chunk), never by the
+table's capacity: on a TPU they run element by element.
 """
 from __future__ import annotations
 
+import contextlib
+import contextvars
+import functools
+import operator
 from typing import NamedTuple, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from .graph_state import (
     INF,
@@ -80,14 +92,60 @@ def _prev(arr, fill):
     return rolled.at[0].set(fill)
 
 
-@jax.jit
-def apply_batch(state: GraphState, ops: OpBatch):
-    """Apply one op batch. Returns ``(new_state, OpResults, overflow)``.
+#: Killed edges whose source ``ecnt`` bumps one trip of the invalidation
+#: loop scatters: a commit that kills ``n`` edges runs ``ceil(n / 1024)``
+#: trips, and none when no vertex was removed.
+_KILL_CHUNK = 1024
 
-    ``overflow`` is True when appended edges did not fit in the slack; the
-    caller must ``compact``/``grow_edges`` and retry (see ``apply_ops``).
-    The input state is never corrupted on overflow (pure function).
+
+def _is_any(x, keys):
+    """bool, shaped like ``x``: ``x`` equals one of the few ``keys``."""
+    return functools.reduce(operator.or_,
+                            [x == keys[i] for i in range(keys.shape[0])])
+
+
+def _bump_killed_sources(ecnt, esrc, kill, n_kill):
+    """``ecnt`` with one bump at the source of every killed slot.
+
+    The killed slots are found ``_KILL_CHUNK`` at a time: a binary search
+    into the running count of kills per row of 128 slots finds each one's
+    row, and a count within the gathered rows finds its lane.  Each loop
+    trip scatters a chunk of sources, so the trip count is
+    ``ceil(n_kill / _KILL_CHUNK)`` whatever the degree of the removed
+    vertices.  The running count is over rows, not slots: a prefix sum of
+    all ``ecap`` slots costs more than the rest of the commit.
     """
+    vcap, ecap = ecnt.shape[0], esrc.shape[0]
+    lanes = 128
+    chunk = jnp.arange(1, _KILL_CHUNK + 1, dtype=jnp.int32)
+
+    def bump(ecnt):
+        rows = jnp.pad(kill, (0, -ecap % lanes)).reshape(-1, lanes)
+        in_row = jnp.sum(rows, axis=1, dtype=jnp.int32)
+        upto = jnp.cumsum(in_row)                   # kills up to each row's end
+
+        def trip(carry):
+            done, ecnt = carry
+            q = done + chunk                        # ranks of this trip's kills
+            row = jnp.minimum(jnp.searchsorted(upto, q, method="scan"),
+                              rows.shape[0] - 1).astype(jnp.int32)
+            k = q - upto[row] + in_row[row]         # rank within its row
+            lane = jnp.sum(jnp.cumsum(rows[row], axis=1, dtype=jnp.int32)
+                           < k[:, None], axis=1, dtype=jnp.int32)
+            slot = jnp.minimum(row * lanes + lane, ecap - 1)
+            src = jnp.where(q <= n_kill, esrc[slot], vcap)
+            return done + _KILL_CHUNK, ecnt.at[src].add(1, mode="drop")
+
+        return lax.while_loop(lambda c: c[0] < n_kill, trip,
+                              (jnp.int32(0), ecnt))[1]
+
+    return lax.cond(n_kill > 0, bump, lambda ecnt: ecnt, ecnt)
+
+
+def _apply_batch(state: GraphState, ops: OpBatch):
+    """``apply_batch`` with the number of edges the batch's RemVs killed."""
+    from repro.kernels import shift_merge
+
     vcap, ecap = state.vcap, state.ecap
     B = ops.kind.shape[0]
     idxs = jnp.arange(B, dtype=jnp.int32)
@@ -112,20 +170,16 @@ def apply_batch(state: GraphState, ops: OpBatch):
     scat_idx = jnp.where(is_last & (sk != NOKEY), sk, vcap)
     alive2 = state.alive.at[scat_idx].set(skind == PUTV, mode="drop")
 
-    # vertices successfully removed at any point in the batch: their incident
-    # edges are invalidated (fresh empty edge-list on re-add, as in the paper).
-    remv_succ = okv & (skind == REMV)
-    had_remv = jnp.zeros((vcap,), jnp.bool_).at[
-        jnp.where(remv_succ, sk, vcap)
-    ].max(jnp.ones((B,), jnp.bool_), mode="drop")
-
-    esrcc = jnp.clip(state.esrc, 0, vcap - 1)
-    edstc = jnp.clip(state.edst, 0, vcap - 1)
+    # Vertices successfully removed at any point in the batch: their incident
+    # edges are invalidated (fresh empty edge-list on re-add, as in the
+    # paper).  A slot dies when an endpoint matches one of the at most B
+    # removed ids: compares fused into one pass over the table.
+    removed = jnp.where(okv & (skind == REMV), sk, NOKEY)
     kill = (state.esrc != NOKEY) & (state.ew < INF) & (
-        had_remv[esrcc] | had_remv[edstc]
-    )
+        _is_any(state.esrc, removed) | _is_any(state.edst, removed))
     ew2 = jnp.where(kill, INF, state.ew)
-    ecnt2 = state.ecnt.at[jnp.where(kill, state.esrc, vcap)].add(1, mode="drop")
+    n_kill = jnp.sum(kill, dtype=jnp.int32)
+    ecnt2 = _bump_killed_sources(state.ecnt, state.esrc, kill, n_kill)
 
     # ---------------- Phase 2: edge ops ---------------------------------
     ise = (ops.kind == PUTE) | (ops.kind == REME)
@@ -187,15 +241,13 @@ def apply_batch(state: GraphState, ops: OpBatch):
     n_app = jnp.sum(app.astype(jnp.int32))
     overflow = used_slots(state) + n_app > ecap
 
-    # Merge-scatter: shift old entries right past their insertion points.
-    pos = pair_searchsorted(state.esrc, state.edst, cu, cv)
-    shift_old = jnp.searchsorted(pos, jnp.arange(ecap, dtype=jnp.int32),
-                                 side="right").astype(jnp.int32)
-    dest_old = jnp.arange(ecap, dtype=jnp.int32) + shift_old
-    esrc3 = jnp.full((ecap,), NOKEY, jnp.int32).at[dest_old].set(state.esrc, mode="drop")
-    edst3 = jnp.full((ecap,), NOKEY, jnp.int32).at[dest_old].set(state.edst, mode="drop")
-    ew4 = jnp.full((ecap,), INF, jnp.float32).at[dest_old].set(ew3, mode="drop")
-    dest_new = jnp.where(cu != NOKEY, pos + jnp.arange(B, dtype=jnp.int32), ecap)
+    # Shift merge: old slot j moves right by the number of appends that sort
+    # before it, a count of B compares per slot; the appends then fill the
+    # holes at their sorted slots ``pos + rank``.
+    pos = jnp.where(cu != NOKEY,
+                    pair_searchsorted(state.esrc, state.edst, cu, cv), ecap)
+    esrc3, edst3, ew4 = shift_merge.spread([state.esrc, state.edst, ew3], pos)
+    dest_new = jnp.where(cu != NOKEY, pos + idxs, ecap)
     esrc3 = esrc3.at[dest_new].set(cu, mode="drop")
     edst3 = edst3.at[dest_new].set(cv, mode="drop")
     ew4 = ew4.at[dest_new].set(cw, mode="drop")
@@ -217,7 +269,58 @@ def apply_batch(state: GraphState, ops: OpBatch):
     ok_out = jnp.where(isge, ge_live, ok_out)
     val_out = jnp.where(isge, ge_w, val_out)
 
-    return new_state, OpResults(ok_out, val_out), overflow
+    return new_state, OpResults(ok_out, val_out), overflow, n_kill
+
+
+# Device traces name a program after its function: the commit that
+# ``apply_ops`` launches keeps the name ``jit_apply_batch``.
+_apply_batch.__name__ = _apply_batch.__qualname__ = "apply_batch"
+_apply_batch_counted = jax.jit(_apply_batch)
+
+
+@jax.jit
+def apply_batch(state: GraphState, ops: OpBatch):
+    """Apply one op batch. Returns ``(new_state, OpResults, overflow)``.
+
+    ``overflow`` is True when appended edges did not fit in the slack; the
+    caller must ``compact``/``grow_edges`` and retry (see ``apply_ops``).
+    The input state is never corrupted on overflow (pure function).
+
+    No step scatters or gathers over the edge table's ``ecap`` slots.  The
+    merge moves each slot right by the number of appends sorted before it
+    in one streaming pass (``kernels/shift_merge.py``), then writes the B
+    appends into the holes.  RemV's invalidation compares each slot with
+    the removed ids and bumps the killed edges' sources a chunk at a time
+    (``_bump_killed_sources``).  Scatters and gathers touch at most ``B``
+    or ``_KILL_CHUNK`` elements.
+    """
+    return _apply_batch_counted(state, ops)[:3]
+
+
+class Invalidations:
+    """Edges killed by the RemVs of the commits ``apply_ops`` made inside
+    :func:`count_invalidations`."""
+
+    __slots__ = ("n",)
+
+    def __init__(self):
+        self.n = 0
+
+
+_INVALIDATIONS: contextvars.ContextVar = contextvars.ContextVar(
+    "repro_invalidations", default=None)
+
+
+@contextlib.contextmanager
+def count_invalidations():
+    """Tally the edges that ``apply_ops`` calls in this block (on this
+    thread) invalidate; yields the :class:`Invalidations` it adds to."""
+    tally = Invalidations()
+    token = _INVALIDATIONS.set(tally)
+    try:
+        yield tally
+    finally:
+        _INVALIDATIONS.reset(token)
 
 
 def apply_ops(state: GraphState, ops: Sequence[Tuple], batch_size: int | None = None):
@@ -228,13 +331,19 @@ def apply_ops(state: GraphState, ops: Sequence[Tuple], batch_size: int | None = 
     append per batch slot — ``grow_edges`` before the single retry.  The
     worst-case bound (``used + B <= ecap``) guarantees the retry cannot
     overflow again, at the cost of occasionally growing a table that a
-    tighter count would have squeezed the batch into.
+    tighter count would have squeezed the batch into.  The committed
+    batch's killed-edge count goes to the innermost ``count_invalidations``
+    block, read in the same transfer as ``overflow``.
     """
     batch = make_batch(ops, batch_size)
     B = int(batch.kind.shape[0])
     while True:
-        new_state, res, overflow = apply_batch(state, batch)
-        if not bool(overflow):
+        new_state, res, overflow, killed = _apply_batch_counted(state, batch)
+        overflow, killed = jax.device_get((overflow, killed))
+        if not overflow:
+            tally = _INVALIDATIONS.get()
+            if tally is not None:
+                tally.n += int(killed)
             return new_state, res
         state = compact(state)
         while int(used_slots(state)) + B > state.ecap:

@@ -61,7 +61,8 @@ which has its own lock.
 Spans (``repro.obs.trace.maybe_span``; profiler annotations even with no
 telemetry): ``lock_wait`` from a submit's entry until it holds the lock,
 and per commit ``commit`` with ``apply`` (the ``apply_batch`` launch and
-its overflow check) and ``ring_commit`` (the dirty set and the append)
+its overflow check; attribute ``killed``, the edges its RemVs
+invalidated) and ``ring_commit`` (the dirty set and the append)
 inside it.
 """
 from __future__ import annotations
@@ -70,7 +71,9 @@ import threading
 from dataclasses import dataclass, field
 from typing import List, Optional, Sequence, Tuple
 
-from repro.core.updates import NOP, PUTE, PUTV, REME, REMV, apply_ops
+from repro.core.updates import (
+    NOP, PUTE, PUTV, REME, REMV, apply_ops, count_invalidations,
+)
 from repro.obs import CounterStruct
 from repro.obs.trace import maybe_span
 from repro.resil.faults import P_SCHED_APPLY, P_SCHED_RING_COMMIT, inject
@@ -83,11 +86,13 @@ _EDGE_OPS = (PUTE, REME)
 
 class SchedulerStats(CounterStruct):
     """Op-log tallies, as ``scheduler_*`` registry counters since PR 6
-    (attribute surface unchanged; see :class:`repro.obs.CounterStruct`)."""
+    (attribute surface unchanged; see :class:`repro.obs.CounterStruct`).
+    ``edges_invalidated`` counts the edges that committed RemVs killed."""
 
     _FIELDS = ("ops_submitted", "ops_committed", "ops_coalesced",
                "batches_committed", "strict_cuts", "commit_failures",
-               "stragglers", "compacts", "compact_failures")
+               "stragglers", "compacts", "compact_failures",
+               "edges_invalidated")
     _PREFIX = "scheduler_"
 
 
@@ -194,9 +199,11 @@ class StreamScheduler:
                 if mon is not None:
                     mon.start()
                 inject(P_SCHED_APPLY)
-                with maybe_span(tracer, "apply"):
+                with maybe_span(tracer, "apply") as asp, \
+                        count_invalidations() as killed:
                     state, _ = apply_ops(self.ring.latest.state, ops,
                                          batch_size=self.batch_size)
+                    asp.set(killed=killed.n)
                 inject(P_SCHED_RING_COMMIT)
                 with maybe_span(tracer, "ring_commit"):
                     entry = self.ring.commit(state)
@@ -220,6 +227,7 @@ class StreamScheduler:
             self.journal.commit_barrier(entry.version, n_raw)
         self.stats.ops_committed += n_raw
         self.stats.batches_committed += 1
+        self.stats.edges_invalidated += killed.n
         if (self.journal is not None and self.compact_every
                 and self.stats.batches_committed % self.compact_every == 0):
             self._auto_compact(entry)

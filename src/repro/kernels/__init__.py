@@ -4,6 +4,8 @@
   * ``minplus_mm``   -- tropical matmul (batched SSSP relax, VPU)
   * ``count_mm``     -- counting matmul (batched Brandes sigma, MXU)
   * ``flash_attention`` -- causal GQA flash attention (LM train/prefill)
+  * ``shift_merge``  -- the commit's merge of a batch into the sorted edge
+                        table, one streaming pass (``core/updates.py``)
 
 Each semiring kernel also has a ``*_mm_masked`` tile-skipping variant driven
 by SMEM occupancy grids (see ``repro.core.tiles``).  Each kernel:

@@ -625,3 +625,32 @@ def test_scheduler_heartbeat_flags_slow_commits():
     assert sum(bool(r.get("straggler")) for r in commits) == mon.stragglers
     assert len(mon.window) == n
     tel.close()
+
+
+def test_scheduler_counts_invalidated_edges():
+    """``edges_invalidated`` adds the live edges each committed RemV
+    killed, counted exactly (a tombstone is not killed twice), stays put
+    across edge-only commits, and is the ``killed`` attribute of the
+    commit's ``apply`` span."""
+    from repro.obs import Telemetry
+
+    g = make_graph(VCAP, ECAP)
+    g, _ = apply_ops(g, [(PUTV, i) for i in range(8)] + [
+        (PUTE, 0, 1, 1.0), (PUTE, 1, 0, 1.0), (PUTE, 2, 1, 1.0),
+        (PUTE, 1, 3, 1.0), (PUTE, 4, 5, 1.0), (PUTE, 1, 6, 1.0)])
+    g, _ = apply_ops(g, [(REME, 1, 6)])          # a tombstone at vertex 1
+    tel = Telemetry.make(None, hlo=False)
+    sched = StreamScheduler(VersionRing(g, depth=8), batch_size=4,
+                            telemetry=tel)
+    sched.submit_many([(PUTE, 4, 6, 2.0), (REME, 4, 5), (PUTE, 6, 7, 1.0),
+                       (PUTE, 0, 2, 1.0)])
+    assert sched.stats.edges_invalidated == 0
+    sched.submit_many([(REMV, 1), (REMV, 7), (PUTE, 3, 4, 1.0), (REMV, 1)])
+    assert sched.stats.edges_invalidated == 4 + 1   # 1's four, 7's one
+    sched.submit_many([(PUTV, 1), (PUTE, 1, 2, 1.0), (PUTE, 2, 3, 1.0),
+                       (REME, 0, 2)])
+    assert sched.stats.edges_invalidated == 5
+    assert sched.stats.batches_committed == 3
+    applies = [r for r in tel.tracer.records if r["span"] == "apply"]
+    assert [r["killed"] for r in applies] == [0, 5, 0]
+    tel.close()

@@ -255,3 +255,36 @@ def test_flash_attention_bf16():
     assert out.dtype == jnp.bfloat16
     assert np.max(np.abs(np.asarray(out, np.float32)
                          - np.asarray(exp, np.float32))) < 3e-2
+
+
+# ---------------- shift merge (the commit's table shift) ----------------
+
+@pytest.mark.parametrize("n,n_pos,n_app", [
+    (256, 16, 16),                 # one short block, padded to the halo
+    (3 * 256 * 128 + 1024, 32, 29),  # three full blocks and a partial one
+    (5000, 1500, 1400),            # a shift past one row of halo rows
+    (4096, 8, 0),                  # no appends: the table comes back whole
+], ids=["short", "partial-block", "wide-halo", "no-appends"])
+def test_shift_merge_moves_each_slot_past_its_appends(n, n_pos, n_app):
+    """``spread`` moves slot j to ``j + #{i: pos[i] <= j}`` (dropping what
+    passes the end) and leaves only the appends' slots unwritten."""
+    from repro.kernels.shift_merge import spread
+
+    rng = np.random.default_rng(n)
+    pos = np.sort(np.concatenate([
+        rng.integers(0, n + 1, n_app - min(n_app, 3)),
+        [0, n // 2, n // 2][:min(n_app, 3)],
+        np.full(n_pos - n_app, n)])).astype(np.int32)
+    cols = [rng.integers(-2**31, 2**31 - 1, n, dtype=np.int64).astype(np.int32),
+            rng.integers(0, 99, n).astype(np.int32),
+            rng.random(n).astype(np.float32)]
+    got = spread([jnp.asarray(c) for c in cols], jnp.asarray(pos))
+    dest = np.arange(n) + np.searchsorted(pos, np.arange(n), side="right")
+    keep = dest < n
+    holes = np.ones(n, bool)
+    holes[dest[keep]] = False
+    assert holes.sum() == min(n_app, n) - (pos[:n_app] + np.arange(n_app)
+                                           >= n).sum()
+    for c, g in zip(cols, got):
+        assert g.dtype == c.dtype
+        assert np.array_equal(np.asarray(g)[dest[keep]], c[keep])

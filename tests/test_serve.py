@@ -541,3 +541,20 @@ def test_served_spans_nest_in_trace_records():
     assert (rung["kind"], rung["rung"], rung["lanes"], rung["pad"]) == \
         ("bfs", "full", 2, 0)
     assert sum(r["span"] == "lock_wait" for r in recs) == 2
+
+
+def test_apply_span_carries_killed_untraced(tmp_path):
+    """With no telemetry, the commit's ``repro.apply`` profiler event
+    still carries ``killed``: the edges its RemVs invalidated."""
+    from repro.core import REMV
+
+    g0 = _path_graph()
+    svc = GraphService(g0, batch_size=2)
+
+    def body():
+        svc.submit_many([(PUTE, 5, 7, 1.0), (PUTE, 9, 11, 1.0)])
+        svc.submit_many([(REMV, 3), (PUTE, 20, 22, 1.0)])
+
+    events = _profiled(tmp_path, body)
+    assert [st["killed"] for n, st in events if n == "apply"] == [0, 2]
+    assert svc.scheduler.stats.edges_invalidated == 2
