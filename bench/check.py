@@ -7,8 +7,9 @@ from in ``PERF.md`` ("Correctness limits").  ``value <= limit`` passes.
   * ``kinds_unanswered`` (query cells): query kinds whose clients got no
     reply in the window, so that nothing of theirs could be compared.
   * ``wrong_entries`` (query cells): BFS hop counts, SSSP distances and BC
-    levels that differ from the reference, plus replies not ``ok``.  Exact,
-    so the limit is 0.
+    levels that differ from the reference, plus replies not ``ok``, plus
+    sharded replies whose shards did not all compute from one version
+    (``agree`` false).  Exact, so the limit is 0.
   * ``bc_rel_err`` (query cells): the largest gap of a BC path count or
     dependency from the reference, relative to ``max(|reference|, 1)``.
     float32 sums in another order than the reference's float64.
@@ -37,6 +38,8 @@ def compare_reply(kind: str, g: HostGraph, src: int, res: dict,
     """``(wrong entries, BC relative error)`` of one host-side reply;
     ``brandes`` is the reference BC (the control passes its own)."""
     wrong = 0 if bool(res["ok"]) else 1
+    if "agree" in res and not bool(res["agree"]):
+        wrong += 1
     if kind == "bfs":
         wrong += int(np.sum(np.asarray(res["dist"], np.int64)
                             != ref_hops(g, src)))

@@ -1,10 +1,11 @@
 """One run of one cell: set-up, the measured window, the check, the result.
 
-The system under test is ``AsyncGraphService(GraphService(state))`` with
-the program's defaults and no telemetry.  Queries enter through
-``query_async``, updates through ``submit``; nothing else of the program
-is driven in the window.  Set-up warms every program the window runs, so
-that nothing compiles inside it (the count is printed).
+The system under test is the service the configuration names (its
+``service`` key; without one, ``GraphService(state)`` on one chip), with
+the program's defaults and no telemetry, behind ``AsyncGraphService``.
+Queries enter through ``query_async``, updates through ``submit``; nothing
+else of the program is driven in the window.  Set-up warms every program
+the window runs, so that nothing compiles inside it (the count is printed).
 """
 from __future__ import annotations
 
@@ -22,7 +23,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from . import check, graphs, reference, workload
+from . import check, graphs, reference, spans, workload
 from . import trace as trace_mod
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -34,6 +35,8 @@ REPLY_TIMEOUT_S = 120.0
 #: that shared a dispatch among them
 SAMPLE_PER_STRATUM = 6
 KINDS = ("bfs", "sssp", "bc")
+#: the service of a configuration without a ``service`` key
+LOCAL = {"kind": "local", "chips": 1}
 
 
 def log(phase: str, **fields) -> None:
@@ -171,6 +174,8 @@ class Run:
     closing: Optional[CommitRec] = None
     counters: Dict[str, int] = field(default_factory=dict)
     trace: Optional[trace_mod.Reduced] = None
+    #: the program's own ``repro.`` spans in the traced window
+    spans: Optional[spans.SpanReduced] = None
 
 
 def _annotate(on: bool, name: str):
@@ -188,6 +193,7 @@ def _counters(svc, srv) -> Dict[str, int]:
             "dispatches": int(fe.dispatches),
             "batched_dispatches": int(fe.batched_dispatches),
             "fallbacks": int(fe.fallbacks),
+            "picked": int(fe.picked),
             "deadline_expired": int(fe.deadline_expired),
             "max_lanes": int(fe.max_batch_seen),
             "ops_committed": int(sc.ops_committed),
@@ -298,6 +304,41 @@ class Window:
 # Set-up
 # --------------------------------------------------------------------------
 
+def service_spec(config: dict) -> dict:
+    """The configuration's ``service``: ``{"kind": "local", "chips": 1}``
+    without the key, or ``{"kind": "sharded", "chips": n, "bc_mode": m}``
+    for ``ShardedGraphService`` over ``n`` chips."""
+    spec = dict(config.get("service", LOCAL))
+    if spec.get("kind") == "local" and spec.get("chips", 1) == 1:
+        return spec
+    if spec.get("kind") == "sharded" and int(spec.get("chips", 0)) >= 1 \
+            and spec.get("bc_mode"):
+        return spec
+    raise ValueError(f"unknown service {spec!r} in configuration "
+                     f"{config.get('name')!r}")
+
+
+def build_service(config: dict, state):
+    """The configuration's service over ``state``, with the program's
+    defaults, and the devices it holds."""
+    import jax
+    from jax.sharding import Mesh
+
+    from repro.engine import GraphService
+    from repro.launch.mesh import make_graph_mesh
+    from repro.shard import ShardedGraphService
+
+    spec = service_spec(config)
+    if spec["kind"] == "local":
+        return GraphService(state), jax.devices()[:1]
+    devices = jax.devices()[:spec["chips"]]
+    if len(devices) < spec["chips"]:
+        raise RuntimeError(f"the service shards over {spec['chips']} chips, "
+                           f"JAX sees {len(devices)}")
+    mesh = make_graph_mesh(Mesh(np.asarray(devices), ("chips",)))
+    return ShardedGraphService(state, mesh, bc_mode=spec["bc_mode"]), devices
+
+
 def _lanes_warmup(svc, state, kinds, max_lanes, rungs, sources, dirty):
     """Run every rung program of ``rungs`` at every lane count up to
     ``max_lanes`` for each kind, through the dispatcher's own batching
@@ -333,6 +374,89 @@ def _lanes_warmup(svc, state, kinds, max_lanes, rungs, sources, dirty):
                                       dirty=dirty))
             out, _ = dispatch_local_group(svc, kind, state, lanes)
             jax.block_until_ready(out)
+
+
+def _sharded_warmup(svc, kinds, sources, commit, commits: int,
+                    growth: int) -> None:
+    """Run every program that the dedup front end
+    (``AsyncGraphService._dispatch_dedup``) and its fallback drive for the
+    sharded service.  Each collect is of one source: per kind, a full
+    collect after the first of ``commits`` commits and a collect through
+    the ladder after each later one; the delta rung on a full and on a delta
+    prior, whichever rung the ladder took; the ladder's test for a revived
+    source; the resilient path a fallback takes (``service.query``); and
+    the tile view's row refresh at every window width and row bucket the
+    run's commits can form.  Leaves the result cache empty, as the local
+    warm-up does."""
+    import jax
+
+    priors = {}
+    for i in range(commits):
+        commit()
+        for kind in kinds:
+            src = sources[kind][0]
+            _, res, mode = svc._traced_collect(kind, src, svc._key(kind, src))
+            jax.block_until_ready(res)
+            if i == 0:
+                if mode != "full":
+                    raise RuntimeError(f"warm-up: {kind} took the {mode} "
+                                       "rung on an empty cache")
+                priors[kind] = (svc.version, res)
+    state = svc.ring.latest.state
+    for kind in kinds:
+        src = sources[kind][0]
+        version, prior = priors[kind]
+        dirty = svc.ring.dirty_between(version, svc.version)
+        for _ in range(2):
+            prior = svc._delta_collect(kind, prior, dirty, src, state)
+            jax.block_until_ready(prior)
+        jax.block_until_ready(svc._revived_source(prior, src, state))
+        jax.block_until_ready(svc.query(kind, src).result)
+    _refresh_warmup(svc, growth)
+    with svc._cache_lock:
+        svc._cache.clear()
+
+
+def _refresh_warmup(svc, growth: int) -> None:
+    """Run the sharded tile view's row refresh (``_rows_refresh_fn``) at
+    every (window width, row bucket) a commit can form: the widths of the
+    tile rows' edge counts at the latest version, give or take ``growth``
+    edges, and every bucket up to ``REFRESH_BATCH``, with padding rows that
+    write nothing back (``refresh_sharded_view``'s own call)."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.shard.tile_shard import (REFRESH_BATCH, ShardedTileView,
+                                        _rows_refresh_fn)
+
+    view = svc.view()
+    state = svc.ring.latest.state
+    esrc = np.asarray(jax.device_get(state.esrc))
+    rows = np.searchsorted(esrc, np.arange(view.n_tiles + 1) * view.tile)
+    counts = np.diff(rows)
+
+    def width(n: int) -> int:            # ``core.tiles.dirty_row_windows``
+        w = 64
+        while w < n:
+            w *= 2
+        return min(w, state.ecap)
+
+    widths, w = [], width(max(0, int(counts.min()) - growth))
+    while w <= width(int(counts.max()) + growth):
+        widths.append(w)
+        if w == state.ecap:
+            break
+        w = min(2 * w, state.ecap)
+    buckets = [1 << i for i in range(REFRESH_BATCH.bit_length())]
+    vw, occ = view.w, view.occ
+    for w in widths:
+        for b in buckets:
+            vw, occ = _rows_refresh_fn(view.mesh, view.tile, w, b)(
+                vw, occ, state.esrc, state.edst, state.ew, state.alive,
+                jnp.asarray(np.full(b, -1, np.int32)),
+                jnp.asarray(np.zeros(b, np.int32)))
+    jax.block_until_ready((vw, occ))
+    svc._view = ShardedTileView(vw, occ, view.mesh, view.tile)
 
 
 def _query_all(srv, pairs) -> list:
@@ -384,8 +508,17 @@ def _sample(queries: List[QueryRec], seed: int) -> List[QueryRec]:
 
 
 def _host_result(res) -> dict:
+    """A reply's result on the host.  A sharded result holds one row per
+    source of its collect (``[S, V]``, ``ok[S]``) and ``agree``; the dedup
+    front end collects one source at a time, so its row is the first."""
     import jax
-    return {k: np.asarray(v) for k, v in jax.device_get(res)._asdict().items()}
+    out = {k: np.asarray(v) for k, v in jax.device_get(res)._asdict().items()}
+    if "agree" not in out:
+        return out
+    if out["ok"].shape != (1,):
+        raise ValueError(f"a sharded reply of {out['ok'].shape[0]} sources")
+    return {k: v[0] if v.ndim and v.shape[0] == 1 else v
+            for k, v in out.items()}
 
 
 def _state_arrays(state):
@@ -480,7 +613,6 @@ def _run(cell, seed, seconds, traced, t_process, config, traffic, bench,
     import jax
 
     from repro.core.graph_state import from_edge_list
-    from repro.engine import GraphService
     from repro.serve import AsyncGraphService
 
     dev = jax.devices()[0]
@@ -497,7 +629,8 @@ def _run(cell, seed, seconds, traced, t_process, config, traffic, bench,
     del src, dst, w
     split["load_s"] = time.perf_counter() - t
 
-    svc = GraphService(state)
+    svc, devices = build_service(config, state)
+    sharded = service_spec(config)["kind"] == "sharded"
     batch = svc.scheduler.batch_size
     warm_commits = 2
     kinds = [k for k in KINDS if traffic.get("clients", {}).get(k)]
@@ -517,20 +650,29 @@ def _run(cell, seed, seconds, traced, t_process, config, traffic, bench,
     try:
         t = time.perf_counter()
         first_op = 0
-        for _ in range(warm_commits):
+
+        def commit():
+            nonlocal first_op
             srv.submit_many(plan.updates[first_op:first_op + batch])
             first_op += batch
             counts[svc.version] = first_op
-        if kinds:
-            # pool sources, or fresh ones from the far end of the clients'
-            # sequences, which no client reaches in a window
-            sources = {k: plan.pools.get(k) or [s for kk, ss in plan.clients
-                                                if kk == k for s in ss[-2:]]
-                       for k in kinds}
-            dirty = svc.ring.dirty_between(0, svc.version)
-            _lanes_warmup(svc, svc.ring.latest.state, kinds, max_lanes,
-                          traffic.get("warm_rungs", ["full"]), sources,
-                          dirty)
+
+        # pool sources, or fresh ones from the far end of the clients'
+        # sequences, which no client reaches in a window
+        sources = {k: plan.pools.get(k) or [s for kk, ss in plan.clients
+                                            if kk == k for s in ss[-2:]]
+                   for k in kinds}
+        if sharded:
+            _sharded_warmup(svc, kinds, sources, commit, warm_commits,
+                            len(plan.updates))
+        else:
+            for _ in range(warm_commits):
+                commit()
+            if kinds:
+                dirty = svc.ring.dirty_between(0, svc.version)
+                _lanes_warmup(svc, svc.ring.latest.state, kinds, max_lanes,
+                              traffic.get("warm_rungs", ["full"]), sources,
+                              dirty)
         split["warmup_s"] = time.perf_counter() - t
         t = time.perf_counter()
         if plan.pools:
@@ -541,9 +683,7 @@ def _run(cell, seed, seconds, traced, t_process, config, traffic, bench,
                                  for s in plan.pools[k][i:i + max_lanes]])
             # one more commit, then each kind once more through the
             # ladder's classification against it
-            srv.submit_many(plan.updates[first_op:first_op + batch])
-            first_op += batch
-            counts[svc.version] = first_op
+            commit()
             _query_all(srv, [(k, plan.pools[k][0]) for k in kinds])
         split["prefill_s"] = time.perf_counter() - t
         if not srv.drain(timeout=600):
@@ -571,7 +711,9 @@ def _run(cell, seed, seconds, traced, t_process, config, traffic, bench,
             raise RuntimeError("queries still in flight after the window")
         for c in win.commits:
             counts[c.version] = c.ops_committed
-        peak = (dev.memory_stats() or {}).get("peak_bytes_in_use")
+        peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")
+                 for d in devices]
+        peak = max(peaks) if None not in peaks else None
         run = Run(cell, seconds, setup_s, win.t0,
                   queries=[q for q in win.queries if q.t_done <= win.t1],
                   commits=[c for c in win.commits if c.t_ret <= win.t1],
@@ -638,9 +780,14 @@ def _run(cell, seed, seconds, traced, t_process, config, traffic, bench,
 
     if traced:
         try:
-            run.trace = trace_mod.reduce(trace_mod.load(logdir))
+            st = spans.from_profile(trace_mod.read_profile(logdir))
         finally:
             shutil.rmtree(logdir, ignore_errors=True)
+        run.trace = trace_mod.reduce(st.base)
+        run.spans = spans.reduce(st)
+        log("trace", idle_by_device=run.trace.idle_by_device,
+            collectives=vars(run.trace.collectives),
+            span_seconds=run.spans.self_s, span_counts=run.spans.count)
     metrics = {}
     for m in metrics_for(bench, cell, traced):
         value = load_reader(m["name"])(run)
@@ -654,6 +801,8 @@ def _run(cell, seed, seconds, traced, t_process, config, traffic, bench,
            "device": {"platform": dev.platform, "kind": dev.device_kind,
                       "count": len(jax.devices()),
                       "memory_peak_bytes": peak}}
+    if len(devices) > 1:
+        out["device"]["memory_peak_bytes_per_chip"] = peaks
     if traced:
         out["device"]["busy_s"] = run.trace.busy_s
         out["device"]["window_s"] = run.trace.window_s

@@ -189,6 +189,8 @@ def test_benchmark_json_resolves_and_keeps_to_the_contract():
         for text in (c["source"], c["why"]):
             assert 1 <= len(text) <= 200 and "\n" not in text
     e2e = {m["name"]: m for m in bench["end_to_end"]}
+    four = sum(w["chips"] == 4 for w in bench["workloads"])
+    assert four <= max(1, len(bench["workloads"]) // 2)
     for w in bench["workloads"]:
         assert set(w) == {"name", "config", "traffic", "chips", "why"}
         assert w["chips"] in (1, 4) and len(w["why"]) <= 200
