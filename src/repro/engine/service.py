@@ -463,13 +463,18 @@ class BaseGraphService:
             self._query_dirty_frac = float(frac)
 
     def _traced_collect(self, kind: str, srcs, key, ladder: bool = True):
-        """``_collect`` wrapped in a child span when tracing is on."""
+        """``_collect`` in a ``collect`` span (a profiler event even with no
+        telemetry): the ``version`` the reply names, its ``mode``, and
+        ``pinned``, the version of the state its rungs read (the sharded
+        service's tile view; else the reply's own)."""
         tel = self.telemetry
-        if tel is None:
-            return self._collect(kind, srcs, key, ladder=ladder)
-        with tel.tracer.span("collect", kind=kind) as sp:
+        with maybe_span(tel.tracer if tel is not None else None, "collect",
+                        kind=kind) as sp:
+            self._query_tls.pinned = None
             entry, res, qmode = self._collect(kind, srcs, key, ladder=ladder)
-            sp.set(version=entry.version, mode=qmode)
+            pinned = self._query_tls.pinned
+            sp.set(version=entry.version, mode=qmode,
+                   pinned=entry.version if pinned is None else pinned)
         return entry, res, qmode
 
     # ------------------------------ queries ------------------------------

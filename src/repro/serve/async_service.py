@@ -35,8 +35,9 @@ compute (the ring has its own lock; neither path holds both).
 Consistency: every reply is exact at the ring version it claims — the
 batched lanes are bit-identical to sequential single-source collects
 (see ``serve.batch``) — and each request linearizes at its admission
-point (local service) or at dispatch (fallback path, which answers at
-the then-latest version and says so in ``reply.version``).
+point (local service) or at dispatch (the sharded service, whose collects
+answer at the then-latest version, and the fallback path; the reply names
+the version in ``reply.version``).
 
 Telemetry (when the wrapped service carries it): ``serve_queue_depth``
 gauge, ``serve_batch_size`` histogram (lanes per compiled dispatch),
@@ -52,8 +53,10 @@ sequential ones.  Inside ``dispatch`` the local path opens ``classify``
 span these are profiler annotations even with no telemetry attached.
 ``ServeStats`` counts, with or without telemetry, the requests the
 dispatcher ``picked`` and their summed admission-to-pick
-``queue_wait_us``, and the ``lanes_run`` and ``pad_lanes`` of the rung
-programs.
+``queue_wait_us``, the ``lanes_run`` and ``pad_lanes`` of the rung
+programs, and the requests ``repinned`` to the latest version at dispatch
+(the dedup path; one ``repin`` span per group, and the
+``serve_repinned`` counter).
 """
 from __future__ import annotations
 
@@ -107,6 +110,7 @@ class ServeStats:
     queue_wait_us: int = 0           # their admission-to-dequeue time
     lanes_run: int = 0               # lanes of rung programs (incl. re-runs)
     pad_lanes: int = 0               # padding lanes pad_pow2 added
+    repinned: int = 0                # requests re-pinned to the latest
     _lock: threading.Lock = field(default_factory=threading.Lock,
                                   repr=False)
 
@@ -374,31 +378,48 @@ class AsyncGraphService:
 
     def _dispatch_dedup(self, kind: str, version: int, entry, reqs):
         """Sharded (or any non-local) service: identical ``(kind, src)``
-        requests at one version share a single collect — the sharded
-        query's source axis is already batched per collect, so the win
-        here is collapsing duplicate request keys; everything else rides
-        the service's own ladder at the latest version."""
+        requests share a single collect — the sharded query's source axis
+        is already batched per collect, so the win here is collapsing
+        duplicate request keys; everything else rides the service's own
+        ladder at the latest version.  A group pinned behind that version
+        is re-pinned to it first; each reply names the version its collect
+        read."""
         svc = self.service
+        if version != svc.ring.latest.version:
+            self._repin(kind, version, reqs)
         by_key = {}
         for req in reqs:
             by_key.setdefault(svc._key(kind, req.src), []).append(req)
         sizes = {"dedup": 0}
         for key, shared in by_key.items():
-            if version == svc.ring.latest.version:
-                entry2, res, mode = svc._traced_collect(
-                    kind, shared[0].src, key)
-                self._note_dispatch(kind, {"dedup": len(shared)})
-                sizes["dedup"] += len(shared)
-                for req in shared:
-                    self._finish(req, res, mode, entry2.version,
-                                 validated=svc._icn_validated(res))
-            else:
-                # The mesh view tracks the latest version only; a group
-                # pinned behind it answers per-request at latest (the
-                # reply names its version) via the resilient path.
-                for req in shared:
-                    self._fallback(req)
+            entry2, res, mode = svc._traced_collect(kind, shared[0].src, key)
+            self._note_dispatch(kind, {"dedup": len(shared)})
+            sizes["dedup"] += len(shared)
+            for req in shared:
+                self._finish(req, res, mode, entry2.version,
+                             validated=svc._icn_validated(res))
         return sizes
+
+    def _repin(self, kind: str, version: int, reqs) -> None:
+        """Move the pins of a group admitted at ``version`` to the latest
+        version: a collect answers at the latest version only, and the
+        older one need not stay resident for these requests."""
+        svc = self.service
+        tel = self._telemetry()
+        with maybe_span(tel.tracer if tel is not None else None, "repin",
+                        kind=kind, lanes=len(reqs), pinned=version) as sp:
+            latest = svc.ring.pin()          # atomic read-latest + pin
+            for req in reqs:
+                req.pin.release()
+                req.pin = svc.ring.pin(latest.version)
+            latest.release()
+            sp.set(version=latest.version)
+        with self.stats._lock:
+            self.stats.repinned += len(reqs)
+        if tel is not None:
+            tel.registry.counter("serve_repinned",
+                                 service=svc._service_name,
+                                 kind=kind).inc(len(reqs))
 
     # ----------------------------- completion ----------------------------
 

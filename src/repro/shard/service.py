@@ -33,9 +33,17 @@ collect; ``"cn"`` double collect across ring versions until two answers
 match.  Each collect additionally carries the psum-validated cross-shard
 version agreement (``result.agree``) — the intra-query half of the
 paper's double-collect check, spanning shards instead of time.
+
+One version per collect: a collect reads and pins the ring's latest entry
+once, refreshes the tile view to exactly that version and runs its rungs
+on it, so the answer is exact at the version the reply names however many
+commits land meanwhile.  Refreshing donates the previous view's buffers,
+so the read, the refresh and the launches of one collect hold the
+collect lock against other collecting threads; commits never take it.
 """
 from __future__ import annotations
 
+import threading
 from typing import Optional, Tuple
 
 import jax
@@ -49,6 +57,7 @@ from repro.engine.incremental import _dirty_stats
 from repro.engine.service import BaseGraphService, QueryReply  # noqa: F401
 from repro.engine.service import ServiceStats  # noqa: F401  (re-export)
 from repro.engine.service import ThresholdSpec
+from repro.engine.version_ring import RingEntry
 from repro.obs import Telemetry
 from repro.obs.trace import annotate as _trace_annotate
 from repro.obs.trace import maybe_span
@@ -122,17 +131,27 @@ class ShardedGraphService(BaseGraphService):
             breaker=breaker, compact_every=compact_every)
         self._view: Optional[ShardedTileView] = None
         self._view_version: int = -1
+        self._collect_lock = threading.RLock()
 
     # ------------------------------- view --------------------------------
 
-    def view(self) -> ShardedTileView:
-        """The sharded tile grid at the latest version, refreshed per shard
-        from the ring's dirty sets (full rebuild on resize / window loss)."""
-        entry = self.ring.latest
-        if self._view is not None and self._view_version == entry.version:
+    def view(self, entry: Optional[RingEntry] = None) -> ShardedTileView:
+        """The sharded tile grid at ``entry``'s version (default: the
+        latest), refreshed per shard from the ring's dirty sets (full
+        rebuild on resize, window loss, or a view ahead of ``entry``).
+        Its version is noted as the current collect's ``pinned``
+        (``_traced_collect``)."""
+        with self._collect_lock:
+            if entry is None:
+                entry = self.ring.latest
+            if self._view is None or self._view_version != entry.version:
+                self._refresh(entry)
+            self._query_tls.pinned = self._view_version
             return self._view
+
+    def _refresh(self, entry: RingEntry) -> None:
         dirty = None
-        if self._view is not None:
+        if self._view is not None and self._view_version < entry.version:
             dirty = self.ring.dirty_between(self._view_version, entry.version)
         tracer = self.telemetry.tracer if self.telemetry is not None else None
         rows0, disp0 = refresh_stats.rows, refresh_stats.dispatches
@@ -144,7 +163,6 @@ class ShardedGraphService(BaseGraphService):
                    rows=refresh_stats.rows - rows0,
                    dispatches=refresh_stats.dispatches - disp0)
         self._view_version = entry.version
-        return self._view
 
     # ------------------------------ queries ------------------------------
 
@@ -191,20 +209,25 @@ class ShardedGraphService(BaseGraphService):
         return bool((~prior.ok & alive_now).any())
 
     def _collect(self, kind: str, srcs, key, ladder: bool = True):
-        """One collect against the latest ring version, climbing the
-        unchanged → delta → full ladder (see module docstring).
+        """One collect at the latest ring version, read and pinned once,
+        climbing the unchanged → delta → full ladder on the tile view of
+        that version (see module docstring).
 
-        ``ladder=False`` (a resilience retry) pins the latest version and
-        dispatches the full distributed query directly — no cache read,
-        no dirty-set math — so a failed delta path cannot poison the
-        retry."""
-        if not ladder:
-            entry = self.ring.latest
-            with self.ring.pin(entry.version):
-                res = self._full_collect(kind, srcs, entry.state)
+        ``ladder=False`` (a resilience retry) dispatches the full
+        distributed query directly — no cache read, no dirty-set math — so
+        a failed delta path cannot poison the retry."""
+        with self._collect_lock, self.ring.pin() as pin:
+            entry = self.ring.get_entry(pin.version)
+            if ladder:
+                mode, res = self._ladder(kind, srcs, key, entry)
+            else:
+                mode, res = "full", self._full_collect(
+                    kind, srcs, entry.state, self.view(entry))
             self._cache_store(key, entry.version, res)
-            return entry, res, "full"
-        entry = self.ring.latest
+            return entry, res, mode
+
+    def _ladder(self, kind: str, srcs, key, entry: RingEntry):
+        """The ladder's rung for ``key`` at ``entry`` -> (mode, result)."""
         state = entry.state
         slot = self._cache.get(key)
         mode, res = "full", None
@@ -236,11 +259,13 @@ class ShardedGraphService(BaseGraphService):
                         elif (frac <= self._threshold(kind)
                               and self._delta_usable(kind, prior, state)):
                             mode, res = "delta", self._delta_collect(
-                                kind, prior, dirty, srcs, state)
+                                kind, prior, dirty, srcs, state,
+                                self.view(entry))
                             if res is None:  # new negcycle: canonical full
                                 mode, res = "full", None
             if res is None:
-                res = self._full_collect(kind, srcs, state)
+                res = self._full_collect(kind, srcs, state,
+                                         self.view(entry))
         except InjectedCrash:
             raise
         except Exception:
@@ -251,15 +276,16 @@ class ShardedGraphService(BaseGraphService):
             raise
         if use_prior:
             self._breaker_success(kind, mode)
-        self._cache_store(key, entry.version, res)
-        return entry, res, mode
+        return mode, res
 
-    def _full_collect(self, kind: str, srcs, state: GraphState):
-        """Dispatch the full distributed query (the ladder's bottom rung)."""
+    def _full_collect(self, kind: str, srcs, state: GraphState,
+                      view: ShardedTileView):
+        """Dispatch the full distributed query (the ladder's bottom rung) on
+        ``view``, the tile view of ``state``."""
         inject(P_COLLECT_DISPATCH)
         acct = self._acct_begin()
         res = _QUERIES[kind](
-            self.view(), state, srcs,
+            view, state, srcs,
             **(self._bc_kwargs() if kind == "bc" else {}),
             use_kernel=self.use_kernel, accountant=acct)
         self._acct_charge(acct)
@@ -269,11 +295,15 @@ class ShardedGraphService(BaseGraphService):
         return {"src_chunk": self.src_chunk, "bc_mode": self.bc_mode}
 
     def _delta_collect(self, kind: str, prior, dirty, srcs,
-                       state: GraphState):
-        """Run the distributed delta query; ``None`` = fall back to full
-        (delta SSSP surfaced a negative cycle born since the prior)."""
+                       state: GraphState,
+                       view: Optional[ShardedTileView] = None):
+        """Run the distributed delta query on ``view``, the tile view of
+        ``state`` (default: the view at the latest version, which ``state``
+        must then be); ``None`` = fall back to full (delta SSSP surfaced a
+        negative cycle born since the prior)."""
         inject(P_COLLECT_DELTA)
-        view = self.view()
+        if view is None:
+            view = self.view()
         acct = self._acct_begin()
         if kind == "bc":
             res = _DELTA[kind](view, state, prior, dirty, srcs,
