@@ -3,12 +3,22 @@
 Non-recursive traversals (the paper's queue/stack machinery) become
 *edge-parallel frontier fixed points* under ``lax.while_loop``:
 
-  * BFS      -- boolean-semiring frontier expansion (scatter-or per level);
+  * BFS      -- boolean-semiring frontier expansion (one segmented min over
+                the in-arcs of every vertex per level);
   * SSSP     -- Bellman-Ford relax to fixed point, plus the paper's
                 CHECKNEGCYCLE: one extra relax pass; any improvement implies a
                 negative cycle reachable from the source;
   * BC       -- Brandes: forward level/sigma counting, backward dependency
                 accumulation per level.
+
+Every per-level reduction from edges to vertices is a *segmented*
+reduction over a sorted edge order (``EdgeView``), never a scatter over
+the ``ecap`` slots: dst-keyed ones (BFS hits and parents, relax minima,
+sigma counts, tree parents) run over a dst-major copy of the edge table,
+sorted once per program and shared by every lane of a batched rung;
+src-keyed ones (BC's backward sums) run over the table itself, which is
+kept sorted by ``(src, dst)``.  A level then costs one edge-long gather of
+a vertex vector, one segmented scan and a ``vcap``-long read-out.
 
 Each query also has a *dense batched* variant (vmap over sources becomes a
 semiring matmul on the MXU -- see ``semiring.py`` / ``repro.kernels``), which
@@ -19,6 +29,7 @@ All functions are pure and jitted; masks/dists are fixed-shape ``[vcap]``.
 """
 from __future__ import annotations
 
+import math
 from functools import partial
 from typing import NamedTuple
 
@@ -51,22 +62,139 @@ class BCResult(NamedTuple):
     level: jax.Array     # int32[vcap]
 
 
-def _edge_views(state: GraphState):
+# ----------------------- sorted edge orders -------------------------------
+
+class EdgeOrder(NamedTuple):
+    """The edge table sorted by a key vertex, laid out for segmented scans.
+
+    The slots of one key vertex form a contiguous segment.  The sorted
+    slots, padded to ``rows * cols``, are laid out column-major as
+    ``[rows, cols]``: column ``c`` holds sorted slots ``c * rows`` to
+    ``(c + 1) * rows - 1`` top to bottom, so a scan runs down the rows of
+    every column at once and only the column tails carry across columns.
+    Slots that are not live (tombstones, dead endpoints, ``NOKEY`` tail
+    and padding) keep their place and are masked by value.
+    """
+
+    src: jax.Array    # int32[rows, cols]  source id (0 where not live)
+    dst: jax.Array    # int32[rows, cols]  destination id (0 where not live)
+    live: jax.Array   # bool[rows, cols]
+    w: jax.Array      # f32[rows, cols]    weight (+inf where not live)
+    run: jax.Array    # int32[rows, cols]  slots of its segment above it, in its column
+    crun: jax.Array   # int32[cols]  columns since the last one a segment starts in
+    last: jax.Array   # int32[vcap]  row * cols + col of the vertex's last slot
+    col: jax.Array    # int32[vcap]  that slot's column
+    carry: jax.Array  # bool[vcap]   the vertex's segment starts in an earlier column
+    has: jax.Array    # bool[vcap]   the vertex has a slot in this order
+
+
+class EdgeView(NamedTuple):
+    """Both orders a query reduces over: ``by_dst`` (dst-major, the
+    in-arcs of each vertex contiguous) and ``by_src`` (the table's own
+    ``(src, dst)`` order, the out-arcs contiguous)."""
+
+    by_dst: EdgeOrder
+    by_src: EdgeOrder
+
+
+def _layout_cols(ecap: int) -> int:
+    """Columns of an order's layout: a multiple of 128 (the lane width)
+    near ``sqrt(ecap)``, so the scan down the rows and the carry across
+    the columns both take about ``log2(ecap) / 2`` steps."""
+    return 128 * max(1, round(math.sqrt(ecap) / 128))
+
+
+def _edge_order(key, esrc, edst, w, vcap: int) -> EdgeOrder:
+    """Lay out an edge table already sorted by ``key`` (``NOKEY`` last);
+    ``w`` is +inf exactly on the slots that are not live."""
+    ecap = key.shape[0]
+    cols = _layout_cols(ecap)
+    rows = -(-ecap // cols)
+    size = rows * cols
+
+    def pad(a, fill):
+        return jnp.concatenate([a, jnp.full((size - ecap,), fill, a.dtype)])
+
+    def grid(a):
+        return a.reshape(cols, rows).T
+
+    key, esrc, edst, w = (pad(key, NOKEY), pad(esrc, NOKEY),
+                          pad(edst, NOKEY), pad(w, INF))
+    live = w < INF
+    ids = jnp.arange(vcap, dtype=jnp.int32)
+    first = jnp.searchsorted(key, ids, side="left").astype(jnp.int32)
+    end = jnp.searchsorted(key, ids, side="right").astype(jnp.int32)
+    last = jnp.maximum(end - 1, 0)
+    col = last // rows
+    pos = jnp.arange(size, dtype=jnp.int32)
+    seg0 = jnp.where(key < vcap, first[jnp.clip(key, 0, vcap - 1)], pos)
+    run = pos - jnp.maximum(seg0, (pos // rows) * rows)
+    starts = (seg0 == pos).reshape(cols, rows).any(axis=1)
+    cids = jnp.arange(cols, dtype=jnp.int32)
+    crun = cids - lax.cummax(jnp.where(starts, cids, 0))
+    return EdgeOrder(
+        src=grid(jnp.where(live, esrc, 0)), dst=grid(jnp.where(live, edst, 0)),
+        live=grid(live), w=grid(w), run=grid(run), crun=crun,
+        last=(last % rows) * cols + col, col=col, carry=first < col * rows,
+        has=end > first)
+
+
+def edge_view(state: GraphState) -> EdgeView:
+    """The state's two edge orders.  The dst-major one costs the one
+    edge-long sort of a program, so a batched rung builds the view once
+    and hands it to every lane; XLA drops an order no query reads."""
+    w = jnp.where(live_edge_mask(state), state.ew, INF)
+    # A stable sort by dst alone keeps each dst's slots in the table's src
+    # order, i.e. the (dst, src) order; two operands and one key compile
+    # several times faster on the TPU than sorting the fields as keys.
+    dkey, perm = lax.sort(
+        (state.edst, jnp.arange(state.ecap, dtype=jnp.int32)), num_keys=1,
+        is_stable=True)
+    return EdgeView(
+        by_dst=_edge_order(dkey, state.esrc[perm], dkey, w[perm], state.vcap),
+        by_src=_edge_order(state.esrc, state.esrc, state.edst, w,
+                           state.vcap))
+
+
+def _seg_scan(x, run, op, ident):
+    """Inclusive segmented scan down axis 0: entry ``i`` becomes ``op``
+    over itself and the ``run[i]`` entries of its segment above it."""
+    n = x.shape[0]
+    d = 1
+    while d < n:
+        above = jnp.concatenate(
+            [jnp.full((d,) + x.shape[1:], ident, x.dtype), x[:-d]])
+        x = jnp.where(run >= d, op(above, x), x)
+        d *= 2
+    return x
+
+
+def seg_reduce(order: EdgeOrder, x, op, ident):
+    """``op`` over each vertex's segment of ``x`` (one value per slot of
+    ``order``, laid out as it is): ``f[vcap]``, ``ident`` where the
+    vertex has no slot.  ``x`` holds ``ident`` wherever a slot must not
+    count."""
+    y = _seg_scan(x, order.run, op, ident)
+    tails = _seg_scan(y[-1], order.crun, op, ident)
+    carry_in = jnp.concatenate([jnp.full((1,), ident, y.dtype), tails[:-1]])
+    out = y.reshape(-1)[order.last]
+    out = jnp.where(order.carry, op(carry_in[order.col], out), out)
+    return jnp.where(order.has, out, ident)
+
+
+def _source_ok(state: GraphState, src):
     vcap = state.vcap
-    live = live_edge_mask(state)
-    srcc = jnp.where(live, state.esrc, 0)
-    dstc = jnp.where(live, state.edst, 0)
-    return live, srcc, dstc
+    return state.alive[jnp.clip(src, 0, vcap - 1)] & (src >= 0) & (src < vcap)
 
 
 # --------------------------------- BFS -----------------------------------
 
-@jax.jit
-def bfs(state: GraphState, src) -> BFSResult:
+def bfs_on(state: GraphState, view: EdgeView, src) -> BFSResult:
+    """``bfs`` over a prebuilt ``view`` of ``state``."""
     src = jnp.asarray(src, jnp.int32)
     vcap = state.vcap
-    live, srcc, dstc = _edge_views(state)
-    ok = state.alive[jnp.clip(src, 0, vcap - 1)] & (src >= 0) & (src < vcap)
+    order = view.by_dst
+    ok = _source_ok(state, src)
 
     reached0 = jnp.zeros((vcap,), jnp.bool_).at[src].set(ok, mode="drop")
     dist0 = jnp.where(reached0, 0, -1).astype(jnp.int32)
@@ -78,11 +206,12 @@ def bfs(state: GraphState, src) -> BFSResult:
 
     def body(carry):
         reached, dist, parent, frontier, lvl = carry
-        act = live & frontier[srcc]
-        hit = jnp.zeros((vcap,), jnp.bool_).at[dstc].max(act, mode="drop")
-        newly = hit & ~reached
-        cand_par = jnp.full((vcap,), NOKEY, jnp.int32).at[dstc].min(
-            jnp.where(act, srcc, NOKEY), mode="drop")
+        act = order.live & frontier[order.src]
+        # min source over the active in-arcs: the candidate parent, and
+        # NOKEY exactly where no in-arc is active (no hit)
+        cand_par = seg_reduce(order, jnp.where(act, order.src, NOKEY),
+                              jnp.minimum, NOKEY)
+        newly = (cand_par != NOKEY) & ~reached
         parent = jnp.where(newly, cand_par, parent)
         dist = jnp.where(newly, lvl + 1, dist)
         return reached | newly, dist, parent, newly, lvl + 1
@@ -92,21 +221,28 @@ def bfs(state: GraphState, src) -> BFSResult:
     return BFSResult(ok, reached, dist, parent)
 
 
+@jax.jit
+def bfs(state: GraphState, src) -> BFSResult:
+    return bfs_on(state, edge_view(state), src)
+
+
 # --------------------------------- SSSP ----------------------------------
 
-def _relax_once(dist, live, srcc, dstc, ew, vcap):
-    cand = jnp.full((vcap,), INF).at[dstc].min(
-        jnp.where(live, dist[srcc] + ew, INF), mode="drop")
+def _relax_once(dist, order: EdgeOrder, w):
+    cand = seg_reduce(order, jnp.where(order.live, dist[order.src] + w, INF),
+                      jnp.minimum, INF)
     return jnp.minimum(dist, cand)
 
 
-def relax_fixpoint(dist0, live, srcc, dstc, ew, vcap):
+def relax_fixpoint(dist0, order: EdgeOrder, w, vcap):
     """Bellman-Ford label-correcting fixed point from admissible upper bounds.
 
-    Returns ``(dist, changed-at-exit, iterations)``.  Shared by ``sssp`` and
-    the engine's delta queries (``repro.engine.incremental``) so the two
-    paths cannot drift apart — their bit-identical guarantee rests on
-    running the exact same relax pass.
+    Each pass is one segmented min over the dst-major ``order`` (``w``:
+    the arc weights laid out as ``order``, or a scalar).  Returns
+    ``(dist, changed-at-exit, iterations)``.  Shared by ``sssp`` and the
+    engine's delta queries (``repro.engine.incremental``) so the two paths
+    cannot drift apart — their bit-identical guarantee rests on running
+    the exact same relax pass.
     """
 
     def cond(carry):
@@ -115,24 +251,23 @@ def relax_fixpoint(dist0, live, srcc, dstc, ew, vcap):
 
     def body(carry):
         dist, _, it = carry
-        nd = _relax_once(dist, live, srcc, dstc, ew, vcap)
+        nd = _relax_once(dist, order, w)
         return nd, (nd < dist).any(), it + 1
 
     return lax.while_loop(cond, body, (dist0, jnp.bool_(True), jnp.int32(0)))
 
 
-@jax.jit
-def sssp(state: GraphState, src) -> SSSPResult:
+def sssp_on(state: GraphState, view: EdgeView, src) -> SSSPResult:
+    """``sssp`` over a prebuilt ``view`` of ``state``."""
     src = jnp.asarray(src, jnp.int32)
     vcap = state.vcap
-    live, srcc, dstc = _edge_views(state)
-    ew = jnp.where(live, state.ew, INF)
-    ok_src = state.alive[jnp.clip(src, 0, vcap - 1)] & (src >= 0) & (src < vcap)
+    order = view.by_dst
+    ok_src = _source_ok(state, src)
 
     dist0 = jnp.full((vcap,), INF).at[src].set(
         jnp.where(ok_src, 0.0, INF), mode="drop")
 
-    dist, changed, _ = relax_fixpoint(dist0, live, srcc, dstc, ew, vcap)
+    dist, changed, _ = relax_fixpoint(dist0, order, order.w, vcap)
 
     # The paper's CHECKNEGCYCLE for free: the fixed-point loop only exits
     # with ``changed`` still True when the vcap-th pass improved something,
@@ -140,19 +275,18 @@ def sssp(state: GraphState, src) -> SSSPResult:
     # negative cycle is reachable — the extra relax pass it would otherwise
     # take to prove convergence is the loop's own final no-change pass.
     negcycle = changed
-
-    # Parent reconstruction: any tight edge dist[v] == dist[u] + w(u,v);
-    # deterministic tie-break = min source id.
-    tight = live & (dist[dstc] == dist[srcc] + ew) & (dist[srcc] < INF)
-    parent = jnp.full((vcap,), NOKEY, jnp.int32).at[dstc].min(
-        jnp.where(tight, srcc, NOKEY), mode="drop")
-    parent = parent.at[jnp.clip(src, 0, vcap - 1)].set(NOKEY)
+    parent = _sssp_parent(order, dist, src)
     return SSSPResult(ok_src & ~negcycle, negcycle, dist, parent)
+
+
+@jax.jit
+def sssp(state: GraphState, src) -> SSSPResult:
+    return sssp_on(state, edge_view(state), src)
 
 
 # ---------------------------------- BC -----------------------------------
 
-def _bc_coo_sweep(live, srcc, dstc, vcap, level0, sigma0, front0, lvl0):
+def _bc_coo_sweep(view: EdgeView, vcap, level0, sigma0, front0, lvl0):
     """Brandes forward + backward over COO edges from a (possibly warm) start.
 
     The shared body of ``bc_dependencies`` (cold start: source frontier at
@@ -161,7 +295,16 @@ def _bc_coo_sweep(live, srcc, dstc, vcap, level0, sigma0, front0, lvl0):
     produce bit-identical results because the loop state at pass ``lvl0``
     equals the cold run's state at that pass — the levels below ``lvl0``
     are required to be exactly what a cold run would have computed.
+
+    A forward level is one segmented sum over the dst-major order: the
+    sigma of each vertex's active in-neighbours.  Every frontier vertex
+    has sigma >= 1 (the source 1, later ones a sum of such), so the sum
+    is positive exactly where an in-arc is active — it is the hit test
+    too.  A backward level is one segmented sum over the table's own
+    src order; the per-arc level test and sigma ratio are fixed once the
+    forward sweep ends, so they are computed once, before it.
     """
+    fwd, bwd = view.by_dst, view.by_src
 
     # Forward phase: levels + shortest-path counts.
     def fcond(carry):
@@ -170,11 +313,9 @@ def _bc_coo_sweep(live, srcc, dstc, vcap, level0, sigma0, front0, lvl0):
 
     def fbody(carry):
         level, sigma, frontier, lvl = carry
-        act = live & frontier[srcc]
-        hit = jnp.zeros((vcap,), jnp.bool_).at[dstc].max(act, mode="drop")
-        newly = hit & (level < 0)
-        adds = jnp.zeros((vcap,), jnp.float32).at[dstc].add(
-            jnp.where(act, sigma[srcc], 0.0), mode="drop")
+        paths = jnp.where(frontier, sigma, 0.0)[fwd.src]
+        adds = seg_reduce(fwd, jnp.where(fwd.live, paths, 0.0), jnp.add, 0.0)
+        newly = (adds > 0) & (level < 0)
         sigma = jnp.where(newly, adds, sigma)
         level = jnp.where(newly, lvl + 1, level)
         return level, sigma, newly, lvl + 1
@@ -185,8 +326,11 @@ def _bc_coo_sweep(live, srcc, dstc, vcap, level0, sigma0, front0, lvl0):
     # Backward phase: delta[u] += sum over tree edges (u,w) at level l->l+1
     # of sigma[u]/sigma[w] * (1 + delta[w]), from the deepest level down
     # (max(level) == deepest reached level; -1 when nothing is reached).
-    sig_src = sigma[srcc]
-    sig_dst = jnp.where(sigma[dstc] > 0, sigma[dstc], 1.0)
+    lvl_src = level[bwd.src]
+    tree_lvl = jnp.where(bwd.live & (level[bwd.dst] == lvl_src + 1),
+                         lvl_src, -1)
+    sig_dst = sigma[bwd.dst]
+    ratio = sigma[bwd.src] / jnp.where(sig_dst > 0, sig_dst, 1.0)
 
     def bcond(carry):
         _, l = carry
@@ -194,11 +338,9 @@ def _bc_coo_sweep(live, srcc, dstc, vcap, level0, sigma0, front0, lvl0):
 
     def bbody(carry):
         delta, l = carry
-        on_lvl = live & (level[srcc] == l) & (level[dstc] == l + 1)
-        contrib = jnp.where(on_lvl, sig_src / sig_dst * (1.0 + delta[dstc]), 0.0)
-        delta = delta + jnp.zeros((vcap,), jnp.float32).at[srcc].add(
-            contrib, mode="drop")
-        return delta, l - 1
+        contrib = jnp.where(tree_lvl == l, ratio * (1.0 + delta[bwd.dst]),
+                            0.0)
+        return delta + seg_reduce(bwd, contrib, jnp.add, 0.0), l - 1
 
     delta, _ = lax.while_loop(
         bcond, bbody, (jnp.zeros((vcap,), jnp.float32), jnp.max(level)))
@@ -206,13 +348,11 @@ def _bc_coo_sweep(live, srcc, dstc, vcap, level0, sigma0, front0, lvl0):
     return level, sigma, delta
 
 
-@jax.jit
-def bc_dependencies(state: GraphState, src) -> BCResult:
-    """Brandes single-source dependency accumulation delta(src | .)."""
+def bc_dependencies_on(state: GraphState, view: EdgeView, src) -> BCResult:
+    """``bc_dependencies`` over a prebuilt ``view`` of ``state``."""
     src = jnp.asarray(src, jnp.int32)
     vcap = state.vcap
-    live, srcc, dstc = _edge_views(state)
-    ok = state.alive[jnp.clip(src, 0, vcap - 1)] & (src >= 0) & (src < vcap)
+    ok = _source_ok(state, src)
 
     level0 = jnp.full((vcap,), -1, jnp.int32).at[src].set(
         jnp.where(ok, 0, -1), mode="drop")
@@ -221,8 +361,14 @@ def bc_dependencies(state: GraphState, src) -> BCResult:
     front0 = level0 == 0
 
     level, sigma, delta = _bc_coo_sweep(
-        live, srcc, dstc, vcap, level0, sigma0, front0, jnp.int32(0))
+        view, vcap, level0, sigma0, front0, jnp.int32(0))
     return BCResult(ok, delta, sigma, level)
+
+
+@jax.jit
+def bc_dependencies(state: GraphState, src) -> BCResult:
+    """Brandes single-source dependency accumulation delta(src | .)."""
+    return bc_dependencies_on(state, edge_view(state), src)
 
 
 @jax.jit
@@ -251,6 +397,28 @@ def bc_level_cut(prior_level, dirty, alive):
 
 # ------------------------ traversal-tree parents ---------------------------
 
+def _bfs_parent(order: EdgeOrder, d, s):
+    """Min source over the tree in-arcs ``dist[u] + 1 == dist[v]``."""
+    vcap = d.shape[0]
+    distf = jnp.where(d >= 0, d.astype(jnp.float32), INF)
+    du = distf[order.src]
+    tree = order.live & (du + 1.0 == distf[order.dst]) & (du < INF)
+    parent = seg_reduce(order, jnp.where(tree, order.src, NOKEY),
+                        jnp.minimum, NOKEY)
+    parent = jnp.where(d >= 0, parent, NOKEY)
+    return parent.at[jnp.clip(s, 0, vcap - 1)].set(NOKEY)
+
+
+def _sssp_parent(order: EdgeOrder, d, s):
+    """Min source over the tight in-arcs ``dist[v] == dist[u] + w(u, v)``."""
+    vcap = d.shape[0]
+    du = d[order.src]
+    tight = order.live & (d[order.dst] == du + order.w) & (du < INF)
+    parent = seg_reduce(order, jnp.where(tight, order.src, NOKEY),
+                        jnp.minimum, NOKEY)
+    return parent.at[jnp.clip(s, 0, vcap - 1)].set(NOKEY)
+
+
 @jax.jit
 def bfs_tree_parents(state: GraphState, dist: jax.Array,
                      srcs: jax.Array) -> jax.Array:
@@ -264,18 +432,7 @@ def bfs_tree_parents(state: GraphState, dist: jax.Array,
     and the sharded queries (``repro.shard.queries``) so every path derives
     parents from one definition.
     """
-    vcap = state.vcap
-    live, srcc, dstc = _edge_views(state)
-
-    def one(d, s):
-        distf = jnp.where(d >= 0, d.astype(jnp.float32), INF)
-        tree = live & (distf[srcc] + 1.0 == distf[dstc]) & (distf[srcc] < INF)
-        parent = jnp.full((vcap,), NOKEY, jnp.int32).at[dstc].min(
-            jnp.where(tree, srcc, NOKEY), mode="drop")
-        parent = jnp.where(d >= 0, parent, NOKEY)
-        return parent.at[jnp.clip(s, 0, vcap - 1)].set(NOKEY)
-
-    return jax.vmap(one)(dist, srcs)
+    return jax.vmap(partial(_bfs_parent, edge_view(state).by_dst))(dist, srcs)
 
 
 @jax.jit
@@ -287,17 +444,8 @@ def sssp_tree_parents(state: GraphState, dist: jax.Array,
     per-source ``queries.sssp``: any tight edge
     ``dist[v] == dist[u] + w(u, v)``, min source id as tie-break.
     """
-    vcap = state.vcap
-    live, srcc, dstc = _edge_views(state)
-    ew = jnp.where(live, state.ew, INF)
-
-    def one(d, s):
-        tight = live & (d[dstc] == d[srcc] + ew) & (d[srcc] < INF)
-        parent = jnp.full((vcap,), NOKEY, jnp.int32).at[dstc].min(
-            jnp.where(tight, srcc, NOKEY), mode="drop")
-        return parent.at[jnp.clip(s, 0, vcap - 1)].set(NOKEY)
-
-    return jax.vmap(one)(dist, srcs)
+    return jax.vmap(partial(_sssp_parent, edge_view(state).by_dst))(dist,
+                                                                    srcs)
 
 
 def bc_map(state: GraphState, v, sources) -> jax.Array:
@@ -306,9 +454,10 @@ def bc_map(state: GraphState, v, sources) -> jax.Array:
     Kept as the oracle/benchmark baseline for ``bc``'s batched path.
     """
     v = jnp.asarray(v, jnp.int32)
+    view = edge_view(state)
 
     def one(s):
-        r = bc_dependencies(state, s)
+        r = bc_dependencies_on(state, view, s)
         return jnp.where(r.ok, r.delta[jnp.clip(v, 0, state.vcap - 1)], 0.0)
 
     return jnp.sum(lax.map(one, jnp.asarray(sources, jnp.int32)))
@@ -565,7 +714,7 @@ def bc_batched_dense(adj_mask: jax.Array, srcs: jax.Array, alive: jax.Array,
     [level[w] = l+1] * (1 + delta[w]) / sigma[w]  is one count_mm against
     the transposed adjacency.  Levels and sigma match per-source
     ``bc_dependencies`` bit-exactly; delta agrees up to float summation
-    order (the scatter-add vs MXU-dot reassociation).
+    order (the COO path's segmented sums vs the MXU dot).
 
     Returns ``(delta[S,V], sigma[S,V], level[S,V], ok[S])``.
 

@@ -51,16 +51,18 @@ from repro.core.graph_state import (
 from repro.core.queries import (
     BCResult,
     BFSResult,
+    EdgeView,
     SSSPResult,
     _bc_coo_sweep,
-    _edge_views,
+    _bfs_parent,
+    _source_ok,
+    _sssp_parent,
     bc_dependencies,
     bc_level_cut,
     bfs,
-    bfs_tree_parents,
+    edge_view,
     relax_fixpoint,
     sssp,
-    sssp_tree_parents,
 )
 from repro.obs.hlo import account_jit
 from repro.obs.trace import annotate as _trace_annotate
@@ -140,18 +142,13 @@ def _dirty_stats(prior_reached: jax.Array, dirty: jax.Array):
 
 # --------------------------------- BFS -----------------------------------
 
-@jax.jit
-def delta_bfs(state: GraphState, prior: BFSResult, dirty: jax.Array,
-              src) -> BFSResult:
-    """Recompute BFS on ``state`` reusing ``prior`` (computed <= dirty ago).
-
-    Bit-identical to ``queries.bfs(state, src)`` for any dirty set that
-    covers the actual changes (a too-large dirty set only costs time).
-    """
+def delta_bfs_on(state: GraphState, view: EdgeView, prior: BFSResult,
+                 dirty: jax.Array, src) -> BFSResult:
+    """``delta_bfs`` over a prebuilt ``view`` of ``state``."""
     src = jnp.asarray(src, jnp.int32)
     vcap = state.vcap
-    live, srcc, dstc = _edge_views(state)
-    ok = state.alive[jnp.clip(src, 0, vcap - 1)] & (src >= 0) & (src < vcap)
+    order = view.by_dst
+    ok = _source_ok(state, src)
 
     priorf = prior.dist.astype(jnp.float32)
     poison = _poison(state, prior.parent, prior.reached, priorf, dirty,
@@ -160,30 +157,36 @@ def delta_bfs(state: GraphState, prior: BFSResult, dirty: jax.Array,
     dist0 = jnp.where(keep, priorf, INF)
     dist0 = dist0.at[src].set(jnp.where(ok, 0.0, INF), mode="drop")
 
-    unit = jnp.ones((state.ecap,), jnp.float32)
-    distf, _, _ = relax_fixpoint(dist0, live, srcc, dstc, unit, vcap)
+    distf, _, _ = relax_fixpoint(dist0, order, 1.0, vcap)
 
     reached = distf < INF
     dist = jnp.where(reached, distf, -1.0).astype(jnp.int32)
     # Parent reconstruction matches queries.bfs exactly (see
     # bfs_tree_parents — shared with the sharded delta path).
-    parent = bfs_tree_parents(state, dist[None], src[None])[0]
+    parent = _bfs_parent(order, dist, src)
     return BFSResult(ok, reached, dist, parent)
+
+
+@jax.jit
+def delta_bfs(state: GraphState, prior: BFSResult, dirty: jax.Array,
+              src) -> BFSResult:
+    """Recompute BFS on ``state`` reusing ``prior`` (computed <= dirty ago).
+
+    Bit-identical to ``queries.bfs(state, src)`` for any dirty set that
+    covers the actual changes (a too-large dirty set only costs time).
+    """
+    return delta_bfs_on(state, edge_view(state), prior, dirty, src)
 
 
 # --------------------------------- SSSP ----------------------------------
 
-@jax.jit
-def delta_sssp(state: GraphState, prior: SSSPResult, dirty: jax.Array,
-               src) -> SSSPResult:
-    """Delta Bellman-Ford; bit-identical to ``queries.sssp`` absent negative
-    cycles (on detection the wrapper re-runs the full query, whose
-    partially-relaxed distances are iteration-order-dependent)."""
+def delta_sssp_on(state: GraphState, view: EdgeView, prior: SSSPResult,
+                  dirty: jax.Array, src) -> SSSPResult:
+    """``delta_sssp`` over a prebuilt ``view`` of ``state``."""
     src = jnp.asarray(src, jnp.int32)
     vcap = state.vcap
-    live, srcc, dstc = _edge_views(state)
-    ew = jnp.where(live, state.ew, INF)
-    ok_src = state.alive[jnp.clip(src, 0, vcap - 1)] & (src >= 0) & (src < vcap)
+    order = view.by_dst
+    ok_src = _source_ok(state, src)
 
     prior_reached = prior.dist < INF
     poison = _poison(state, prior.parent, prior_reached, prior.dist, dirty,
@@ -192,15 +195,24 @@ def delta_sssp(state: GraphState, prior: SSSPResult, dirty: jax.Array,
     dist0 = jnp.where(keep, prior.dist, INF)
     dist0 = dist0.at[src].set(jnp.where(ok_src, 0.0, INF), mode="drop")
 
-    dist, changed, _ = relax_fixpoint(dist0, live, srcc, dstc, ew, vcap)
+    dist, changed, _ = relax_fixpoint(dist0, order, order.w, vcap)
 
     # Same free CHECKNEGCYCLE as queries.sssp: from *any* admissible upper
     # bound, Bellman-Ford converges within vcap-1 passes absent a negative
     # cycle, so exiting the loop still-changed == negative cycle.
     negcycle = changed
 
-    parent = sssp_tree_parents(state, dist[None], src[None])[0]
+    parent = _sssp_parent(order, dist, src)
     return SSSPResult(ok_src & ~negcycle, negcycle, dist, parent)
+
+
+@jax.jit
+def delta_sssp(state: GraphState, prior: SSSPResult, dirty: jax.Array,
+               src) -> SSSPResult:
+    """Delta Bellman-Ford; bit-identical to ``queries.sssp`` absent negative
+    cycles (on detection the wrapper re-runs the full query, whose
+    partially-relaxed distances are iteration-order-dependent)."""
+    return delta_sssp_on(state, edge_view(state), prior, dirty, src)
 
 
 # ---------------------------------- BC -----------------------------------
@@ -229,16 +241,12 @@ def delta_bc(state: GraphState, prior: BCResult, dirty: jax.Array,
     return _delta_bc_at_cut(state, prior, cut, src)
 
 
-@jax.jit
-def _delta_bc_at_cut(state: GraphState, prior: BCResult, cut,
-                     src) -> BCResult:
-    """``delta_bc`` with the cut already computed (``incremental_bc``
-    evaluates it once for its host-side gate and passes the device scalar
-    through rather than re-deriving it under jit)."""
+def delta_bc_on(state: GraphState, view: EdgeView, prior: BCResult, cut,
+                src) -> BCResult:
+    """``_delta_bc_at_cut`` over a prebuilt ``view`` of ``state``."""
     src = jnp.asarray(src, jnp.int32)
     vcap = state.vcap
-    live, srcc, dstc = _edge_views(state)
-    ok = state.alive[jnp.clip(src, 0, vcap - 1)] & (src >= 0) & (src < vcap)
+    ok = _source_ok(state, src)
 
     cut = jnp.asarray(cut, jnp.int32)
     keep = (prior.level >= 0) & (prior.level < cut)
@@ -247,8 +255,17 @@ def _delta_bc_at_cut(state: GraphState, prior: BCResult, cut,
     front0 = level0 == cut - 1
 
     level, sigma, delta = _bc_coo_sweep(
-        live, srcc, dstc, vcap, level0, sigma0, front0, cut - 1)
+        view, vcap, level0, sigma0, front0, cut - 1)
     return BCResult(ok, delta, sigma, level)
+
+
+@jax.jit
+def _delta_bc_at_cut(state: GraphState, prior: BCResult, cut,
+                     src) -> BCResult:
+    """``delta_bc`` with the cut already computed (``incremental_bc``
+    evaluates it once for its host-side gate and passes the device scalar
+    through rather than re-deriving it under jit)."""
+    return delta_bc_on(state, edge_view(state), prior, cut, src)
 
 
 # ----------------------------- host wrappers ------------------------------

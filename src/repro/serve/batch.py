@@ -3,15 +3,21 @@
 The dispatcher groups admitted requests by ``(kind, version)`` and this
 module turns each group into at most two compiled calls:
 
-  * the **full** rung maps the single-source query (``queries.bfs`` /
-    ``sssp`` / ``bc_dependencies``) over the stacked source axis — N
-    concurrent BFS queries at version ``v`` cost one compiled program
+  * the **full** rung maps the single-source query (``queries.bfs_on`` /
+    ``sssp_on`` / ``bc_dependencies_on``) over the stacked source axis —
+    N concurrent BFS queries at version ``v`` cost one compiled program
     instead of N dispatches;
-  * the **delta** rung maps the engine's delta kernels (``delta_bfs`` /
-    ``delta_sssp`` / ``_delta_bc_at_cut``) over stacked
+  * the **delta** rung maps the engine's delta kernels (``delta_bfs_on``
+    / ``delta_sssp_on`` / ``delta_bc_on``) over stacked
     ``(prior, dirty, src)`` lanes — each lane carries its own prior and
     its own accumulated dirty mask, so requests cached at *different*
     earlier versions still share the dispatch.
+
+Every program first builds the state's ``queries.edge_view``: the
+dst-major copy of the edge table (the program's one edge-long sort) and
+the segment layout of both orders.  Every lane then reduces each level
+over that shared view with segmented scans instead of scattering over
+the ``ecap`` slots, so no lane pays for a sort of its own.
 
 The lanes run one after another inside the program (``lax.map``), not
 side by side (``jax.vmap``): the vmapped gathers over the ``ecap``-long
@@ -47,28 +53,33 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.core import queries
-from repro.engine.incremental import _delta_bc_at_cut, _dirty_stats, \
-    delta_bfs, delta_sssp
+from repro.engine.incremental import _dirty_stats, delta_bc_on, \
+    delta_bfs_on, delta_sssp_on
 from repro.obs.trace import maybe_span
 
 __all__ = ["Lane", "classify_local", "dispatch_local_group", "pad_pow2"]
 
 
+def _map_lanes(query, state, lanes):
+    view = queries.edge_view(state)
+    return lax.map(lambda lane: query(state, view, *lane), lanes)
+
+
 def _lanes(query):
-    """``query(state, *lane_args)`` mapped over stacked lanes in one
-    compiled program; the state is shared by every lane."""
-    return jax.jit(lambda state, *lanes: lax.map(
-        lambda lane: query(state, *lane), lanes))
+    """``query(state, view, *lane_args)`` mapped over stacked lanes in one
+    compiled program; the state and its edge view (the program's one
+    edge-long sort) are shared by every lane."""
+    return jax.jit(lambda state, *lanes: _map_lanes(query, state, lanes))
 
 
 #: full rungs: state shared, source axis stacked.
-_VFULL = {"bfs": _lanes(queries.bfs), "sssp": _lanes(queries.sssp),
-          "bc": _lanes(queries.bc_dependencies)}
+_VFULL = {"bfs": _lanes(queries.bfs_on), "sssp": _lanes(queries.sssp_on),
+          "bc": _lanes(queries.bc_dependencies_on)}
 
 #: delta rungs: state shared; prior / dirty-or-cut / source stacked per
 #: lane.
-_VDELTA = {"bfs": _lanes(delta_bfs), "sssp": _lanes(delta_sssp),
-           "bc": _lanes(_delta_bc_at_cut)}
+_VDELTA = {"bfs": _lanes(delta_bfs_on), "sssp": _lanes(delta_sssp_on),
+           "bc": _lanes(delta_bc_on)}
 
 #: reached-region mask of a cached local result, per kind (the unchanged
 #: test: dirty ∩ reached == ∅ ⇒ the cached answer stands).

@@ -78,11 +78,12 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.core import semiring
 from repro.core.graph_state import INF, GraphState
 from repro.core.queries import (
-    _edge_views,
     bc_batched_dense,
     bc_batched_ops,
     bc_level_cut,
     bfs_tree_parents,
+    edge_view,
+    seg_reduce,
     sssp_tree_parents,
 )
 
@@ -755,12 +756,12 @@ def _sssp_delta_dist0(state: GraphState, prior_dist, prior_parent, dirty,
 
     dist0 = jax.vmap(one)(prior_dist, prior_parent, srcs)
 
-    live, srcc, dstc = _edge_views(state)
+    out_arcs = edge_view(state).by_src
 
     def gap_rows(d):
-        gap = live & (d[srcc] < INF) & (d[dstc] == INF)
-        return (jnp.zeros((vcap,), jnp.bool_)
-                .at[srcc].max(gap, mode="drop"))
+        gap = (out_arcs.live & (d[out_arcs.src] < INF)
+               & (d[out_arcs.dst] == INF))
+        return seg_reduce(out_arcs, gap, jnp.logical_or, False)
 
     finite_any = (dist0 < INF).any(axis=0)
     active0 = (dirty & finite_any) | jax.vmap(gap_rows)(dist0).any(axis=0)

@@ -160,3 +160,65 @@ def test_commit_compiles_for_v5e(one_chip, for_tpu):
     compiled = _commit_at_paper_rmat_widths(one_chip).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * 4 * 15_728_640
+
+
+#: ``g500-served``'s widths (scale 15) and a dispatch of four lanes.
+G500_VCAP, G500_ECAP, G500_LANES = 32_768, 1_572_864, 4
+
+
+def _rung_lowered(one_chip, kind, rung):
+    """``batch._VFULL[kind]`` or ``_VDELTA[kind]`` lowered at g500-served's
+    widths for four stacked lanes."""
+    from repro.core.queries import BCResult, BFSResult, SSSPResult
+
+    lanes, vcap = G500_LANES, G500_VCAP
+    state = _state_sds(one_chip, vcap, G500_ECAP)
+    srcs = _sds(one_chip, (lanes,), jnp.int32)
+    if rung == "full":
+        return batch._VFULL[kind].lower(state, srcs)
+    row = {dt: _sds(one_chip, (lanes, vcap), dt)
+           for dt in (jnp.bool_, jnp.int32, jnp.float32)}
+    one = _sds(one_chip, (lanes,), jnp.bool_)
+    prior, extra = {
+        "bfs": (BFSResult(one, row[jnp.bool_], row[jnp.int32],
+                          row[jnp.int32]), row[jnp.bool_]),
+        "sssp": (SSSPResult(one, one, row[jnp.float32], row[jnp.int32]),
+                 row[jnp.bool_]),
+        "bc": (BCResult(one, row[jnp.float32], row[jnp.float32],
+                        row[jnp.int32]), _sds(one_chip, (lanes,), jnp.int32)),
+    }[kind]
+    return batch._VDELTA[kind].lower(state, prior, extra, srcs)
+
+
+def _edge_sorts(lowered, span):
+    """For each sort in the lowered program with an operand of ``span`` or
+    more elements: whether it sits inside a loop (runs once per lane)."""
+    from jax._src.lib.mlir import ir
+
+    def walk(op, in_loop):
+        for region in op.regions:
+            for block in region.blocks:
+                for inner in block.operations:
+                    name = inner.operation.name
+                    if name == "stablehlo.sort" and any(
+                            max(ir.RankedTensorType(v.type).shape, default=1)
+                            >= span for v in inner.operands):
+                        yield in_loop
+                    yield from walk(inner, in_loop or name == "stablehlo.while")
+
+    return list(walk(lowered.compiler_ir("stablehlo").operation, False))
+
+
+@pytest.mark.parametrize("rung", ["full", "delta"])
+@pytest.mark.parametrize("kind", ["bfs", "sssp", "bc"])
+def test_rung_scatters_no_edge_slots_and_sorts_once(one_chip, for_tpu, kind,
+                                                    rung):
+    """Every local COO rung program, at g500-served's widths: no scatter's
+    indices or updates span the edge table (each level reduces over a
+    sorted order with segmented scans), and the program holds exactly one
+    edge-long sort, outside the lane loop, shared by its lanes."""
+    lowered = _rung_lowered(one_chip, kind, rung)
+    scatters = [size for name, size in _index_sizes(lowered)
+                if name == "stablehlo.scatter"]
+    assert max(scatters, default=0) < G500_ECAP, scatters
+    assert _edge_sorts(lowered, G500_ECAP) == [False]
