@@ -16,7 +16,7 @@ scaling ratio only on a real mesh.  What it reports:
   * **per-shard tile-skip hit rate** — what fraction of its band each
     shard's masked kernels elide;
   * **collective bytes per level** — measured from the compiled HLO
-    (``launch.dryrun.parse_collective_bytes`` on the while-loop body) next
+    (``obs.hlo.parse_collective_bytes`` on the while-loop body) next
     to the formula value S x Vp x (1B bfs | 4B sssp);
   * **ring vs gather BC** — the SUMMA band-rotation BC (``bc_mode="ring"``)
     against the all-gather oracle: wall time, per-device temp bytes off
@@ -71,6 +71,7 @@ import jax.numpy as jnp  # noqa: E402
 from repro.core import PUTE, REME, apply_ops, queries  # noqa: E402
 from repro.core.updates import dirty_vertices  # noqa: E402
 from repro.data import load_rmat_graph  # noqa: E402
+from repro.obs.hlo import parse_collective_bytes  # noqa: E402
 from repro.shard import (  # noqa: E402
     ShardedGraphService,
     as_graph_mesh,
@@ -189,9 +190,6 @@ def _collective_bytes(mesh, view, g, kind, srcs, src_chunk=None):
     """Per-level collective bytes off the compiled HLO (the while body's
     all-reduce — and the ring's band permutes — appear once per loop in
     the program text)."""
-    # Deferred import: dryrun prepends its own 512-device XLA flag on
-    # import, which must never race this benchmark's --devices flag.
-    from repro.launch.dryrun import parse_collective_bytes
     txt = _compiled(mesh, view, g, kind, srcs, src_chunk).as_text()
     return parse_collective_bytes(txt)
 

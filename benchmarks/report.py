@@ -1,16 +1,17 @@
-"""Emit the EXPERIMENTS.md §Dry-run and §Roofline tables from artifacts,
-plus the shared ``bench_metadata()`` header every BENCH_*.json emitter
-stamps into its payload (schema version, git sha, device inventory)."""
+"""Emit the graph-engine dry-run table (``launch/dryrun.py --graph``
+artifacts), plus the shared ``bench_metadata()`` header every BENCH_*.json
+emitter stamps into its payload (schema version, git sha, device
+inventory)."""
 import datetime
 import glob
 import json
 import os
 import subprocess
-import sys
-
-sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 BENCH_SCHEMA_VERSION = 1
+
+DRYRUN_DIR = os.path.join(os.path.dirname(__file__), "..", "experiments",
+                          "dryrun")
 
 
 def _git_sha():
@@ -43,36 +44,10 @@ def bench_metadata():
     }
 
 
-def dryrun_table():
-    import roofline
-    rows = []
-    for fn in sorted(glob.glob(os.path.join(roofline.DEFAULT_DIR, "*.json"))):
-        rec = json.load(open(fn))
-        if rec.get("arch") == "graph_engine":
-            continue
-        if rec.get("skipped"):
-            rows.append((rec["arch"], rec["shape"], rec["mesh"], "SKIP",
-                         rec["reason"], ""))
-            continue
-        mem = rec["full"].get("memory", {})
-        coll = rec["full"].get("collectives", {})
-        rows.append((
-            rec["arch"], rec["shape"], rec["mesh"],
-            f"ok ({rec['compile_s']}s)",
-            f"{mem.get('peak_bytes', 0)/2**30:.1f} GiB",
-            f"{coll.get('total', 0)/2**30:.2f} GiB/{coll.get('count', 0)}"))
-    out = ["| arch | shape | mesh | compile | peak/dev | HLO coll bytes/ops |",
-           "|---|---|---|---|---|---|"]
-    for r in rows:
-        out.append("| " + " | ".join(str(x) for x in r) + " |")
-    return "\n".join(out)
-
-
 def graph_table():
-    import roofline
     out = ["| cell | mesh | query | per-level coll | flops(body) | compile |",
            "|---|---|---|---|---|---|"]
-    for fn in sorted(glob.glob(os.path.join(roofline.DEFAULT_DIR,
+    for fn in sorted(glob.glob(os.path.join(DRYRUN_DIR,
                                             "graph_engine*.json"))):
         rec = json.load(open(fn))
         name = os.path.basename(fn).replace(".json", "")
@@ -89,13 +64,4 @@ def graph_table():
 
 
 if __name__ == "__main__":
-    import roofline
-    which = sys.argv[1] if len(sys.argv) > 1 else "all"
-    if which in ("all", "dryrun"):
-        print(dryrun_table())
-        print()
-    if which in ("all", "graph"):
-        print(graph_table())
-        print()
-    if which in ("all", "roofline"):
-        print(roofline.markdown(roofline.table()))
+    print(graph_table())

@@ -117,22 +117,6 @@ def bench_semiring_dense(n=512):
          "jnp_path")
 
 
-# ----- Roofline summary (reads dry-run artifacts when present) -------------
-
-def roofline_summary():
-    import roofline
-    try:
-        rows = roofline.table()
-    except Exception as e:
-        _row("roofline", 0.0, f"unavailable:{e}")
-        return
-    for r in rows:
-        _row(f"roofline_{r['arch']}_{r['shape']}",
-             max(r["t_compute"], r["t_memory"], r["t_collective"]) * 1e6,
-             f"dom={r['dominant']};mfu_bound={r['mfu_bound']:.3f};"
-             f"useful={r['useful_ratio']:.2f}")
-
-
 def main() -> None:
     print("name,us_per_call,derived")
     fig678_query_latency()
@@ -140,7 +124,6 @@ def main() -> None:
     fig1213_scan_stats()
     bench_update_throughput()
     bench_semiring_dense()
-    roofline_summary()
 
 
 if __name__ == "__main__":

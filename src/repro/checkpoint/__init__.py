@@ -1,4 +1,3 @@
 from .checkpointer import (  # noqa: F401
-    Checkpointer, latest_step, read_manifest, save_checkpoint,
-    restore_checkpoint,
+    latest_step, read_manifest, restore_checkpoint, save_checkpoint,
 )

@@ -1,6 +1,6 @@
 """Versioned checkpoint store with PANIGRAHAM-style snapshot validation.
 
-This is where the paper's technique genuinely generalizes to the LM stack:
+The graph journal (``resil/journal.py``) stores its base snapshots here:
 
   * every save commits a new **version** (monotonic counter) and writes the
     manifest LAST, atomically (tmp + rename) — a manifest is the analogue of
@@ -17,8 +17,8 @@ This is where the paper's technique genuinely generalizes to the LM stack:
 
 **Elastic resharding**: leaves are stored as full (unsharded) arrays keyed by
 tree path; ``restore_checkpoint(..., mesh, specs)`` re-places them under ANY
-mesh/sharding — restarting 512-chip training on 256 chips (or 2 pods on 1)
-is a restore, not a migration.
+mesh/sharding — restoring a snapshot taken on one device layout onto
+another is a restore, not a migration.
 """
 from __future__ import annotations
 
@@ -26,7 +26,6 @@ import hashlib
 import json
 import os
 import tempfile
-import threading
 import time
 from typing import Optional
 
@@ -152,54 +151,3 @@ def restore_checkpoint(ckpt_dir: str, step: int, tree_like, *,
         out.append(arr)
     return jax.tree_util.tree_unflatten(treedef, out)
 
-
-class Checkpointer:
-    """Async checkpointer: saves on a background thread so the train loop
-    never blocks on disk (the non-blocking-update half of the paper's dial),
-    with version counters shared with the restore-side validation."""
-
-    def __init__(self, ckpt_dir: str, keep: int = 3):
-        self.ckpt_dir = ckpt_dir
-        self.keep = keep
-        self.version = 0
-        self._thread: Optional[threading.Thread] = None
-        os.makedirs(ckpt_dir, exist_ok=True)
-
-    def save(self, step: int, tree, blocking: bool = False):
-        self.version += 1
-        version = self.version
-        host_tree = jax.tree.map(lambda x: np.asarray(jax.device_get(x)), tree)
-        self.wait()
-
-        def work():
-            save_checkpoint(self.ckpt_dir, step, host_tree, version=version)
-            self._gc()
-
-        if blocking:
-            work()
-        else:
-            self._thread = threading.Thread(target=work, daemon=True)
-            self._thread.start()
-
-    def wait(self):
-        if self._thread is not None:
-            self._thread.join()
-            self._thread = None
-
-    def _gc(self):
-        steps = sorted(
-            int(n.split("_")[1]) for n in os.listdir(self.ckpt_dir)
-            if n.startswith("step_"))
-        for s in steps[:-self.keep]:
-            d = os.path.join(self.ckpt_dir, f"step_{s:08d}")
-            for f in os.listdir(d):
-                os.remove(os.path.join(d, f))
-            os.rmdir(d)
-
-    def restore_latest(self, tree_like, mesh=None, specs=None):
-        step = latest_step(self.ckpt_dir)
-        if step is None:
-            return None, None
-        tree = restore_checkpoint(self.ckpt_dir, step, tree_like,
-                                  mesh=mesh, specs=specs)
-        return step, tree

@@ -1,2 +1,1 @@
-from .pipeline import SyntheticTokens, shard_batch  # noqa: F401
 from .rmat import rmat_edges, load_rmat_graph  # noqa: F401

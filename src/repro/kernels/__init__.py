@@ -3,7 +3,6 @@
   * ``bool_mm``      -- boolean-semiring matmul (batched BFS, MXU)
   * ``minplus_mm``   -- tropical matmul (batched SSSP relax, VPU)
   * ``count_mm``     -- counting matmul (batched Brandes sigma, MXU)
-  * ``flash_attention`` -- causal GQA flash attention (LM train/prefill)
   * ``shift_merge``  -- the commit's merge of a batch into the sorted edge
                         table, one streaming pass (``core/updates.py``)
 

@@ -23,7 +23,6 @@ import numpy as np
 from . import bool_mm as _bool
 from . import count_mm as _count
 from . import minplus_mm as _minplus
-from . import flash_attention as _flash
 from .backend import check_amask
 
 
@@ -134,10 +133,3 @@ def count_mm(s: jax.Array, a: jax.Array, bm: int = 128, bn: int = 128,
         out = _count.count_mm_masked(sp, ap, smask, am, bm=bm, bn=bn, bk=bk)
     return out[:m, :n]
 
-
-def flash_attention(q, k, v, causal: bool = True, sm_scale=None, window=None,
-                    bq: int = 128, bk: int = 128):
-    """Causal GQA flash attention; q [B,Hq,S,D], kv [B,Hkv,S,D]."""
-    return _flash.flash_attention(
-        q, k, v, causal=causal, sm_scale=sm_scale, window=window,
-        bq=bq, bk=bk)

@@ -21,28 +21,3 @@ def count_mm_ref(s: jax.Array, a: jax.Array) -> jax.Array:
     return jnp.dot(s.astype(jnp.float32), a.astype(jnp.float32),
                    precision=jax.lax.Precision.HIGHEST)
 
-
-def flash_attention_ref(q: jax.Array, k: jax.Array, v: jax.Array,
-                        causal: bool = True,
-                        sm_scale: float | None = None) -> jax.Array:
-    """GQA attention oracle.
-
-    q: [B, Hq, Sq, D]; k, v: [B, Hkv, Skv, D] with Hq % Hkv == 0.
-    Causal masking aligns the *ends* of q and kv (decode/prefill convention):
-    query i attends to kv j iff j <= i + (Skv - Sq).
-    """
-    b, hq, sq, d = q.shape
-    hkv, skv = k.shape[1], k.shape[2]
-    group = hq // hkv
-    scale = sm_scale if sm_scale is not None else 1.0 / jnp.sqrt(d).astype(q.dtype)
-    kq = jnp.repeat(k, group, axis=1)
-    vq = jnp.repeat(v, group, axis=1)
-    logits = jnp.einsum("bhqd,bhkd->bhqk", q, kq,
-                        precision=jax.lax.Precision.HIGHEST) * scale
-    if causal:
-        offs = skv - sq
-        mask = jnp.arange(skv)[None, :] <= (jnp.arange(sq)[:, None] + offs)
-        logits = jnp.where(mask[None, None], logits, -jnp.inf)
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1).astype(q.dtype)
-    return jnp.einsum("bhqk,bhkd->bhqd", probs, vq,
-                      precision=jax.lax.Precision.HIGHEST)

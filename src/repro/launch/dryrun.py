@@ -1,21 +1,15 @@
-"""Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
+"""Multi-pod dry-run: lower + compile the graph engine's distributed queries
+on the production mesh.
 
-The FIRST two lines (before any other import, including repro) create 512
+The FIRST lines (before any other import, including repro) create 512
 placeholder host devices so ``jax.make_mesh`` can build the production mesh
-on this CPU-only container.  Never set this flag globally — smoke tests and
+on a CPU-only host.  Never set this flag globally — smoke tests and
 benchmarks must see 1 device.
 
-Per cell this driver can produce up to three compiles:
-  * full depth           -> proves it compiles + memory_analysis (fits/chip)
-  * depth d1=1, d2=2     -> (single-pod only) two-point depth extrapolation
-    of FLOPs / bytes / collective-bytes, because XLA's HloCostAnalysis
-    visits a ``lax.scan`` body ONCE regardless of trip count (verified in
-    EXPERIMENTS.md §Dry-run) — per-layer deltas x true depth recover the
-    real totals.  Inner chunk loops (attention q-blocks, chunked xent) are
-    python-unrolled in the model code for exactly this reason.
+    PYTHONPATH=src python -m repro.launch.dryrun --graph [--both-meshes]
 
-Results are written incrementally to experiments/dryrun/*.json; the roofline
-table (benchmarks/roofline.py, EXPERIMENTS.md §Roofline) reads them.
+Results are written to experiments/dryrun/graph_engine.<mesh>.json;
+``benchmarks/report.py`` tabulates them.
 """
 import os
 os.environ["XLA_FLAGS"] = (
@@ -23,117 +17,17 @@ os.environ["XLA_FLAGS"] = (
     + os.environ.get("XLA_FLAGS", ""))
 
 import argparse      # noqa: E402
-import dataclasses   # noqa: E402
 import json          # noqa: E402
-import re            # noqa: E402
 import time          # noqa: E402
 import traceback     # noqa: E402
 
 import jax           # noqa: E402
-import jax.numpy as jnp  # noqa: E402
 
-from repro.configs import ARCHS, SHAPES, get_config, shapes_for  # noqa: E402
-from repro.models import get_model, input_specs  # noqa: E402
-from repro.models.sharding_ctx import sharding_context  # noqa: E402
-from repro.optim import adamw_init  # noqa: E402
 from repro.launch import mesh as meshlib  # noqa: E402
-from repro.launch import steps as steplib  # noqa: E402
+from repro.obs.hlo import parse_collective_bytes  # noqa: E402
 
 OUT_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                        "experiments", "dryrun")
-
-_DTYPE_BYTES = {
-    "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
-    "s64": 8, "u64": 8, "s32": 4, "u32": 4, "s16": 2, "u16": 2,
-    "s8": 1, "u8": 1, "pred": 1,
-}
-
-_COLL_RE = re.compile(
-    r"=\s*((?:\([^)]*\))|(?:\w+\[[^\]]*\]\S*))\s+"
-    r"(all-reduce|all-gather|reduce-scatter|all-to-all|collective-permute)"
-    r"(?:-start)?\(")
-_SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
-
-
-def parse_collective_bytes(hlo_text: str) -> dict:
-    """Sum result bytes of every collective op in the per-device HLO."""
-    out = {}
-    for m in _COLL_RE.finditer(hlo_text):
-        shapes, kind = m.group(1), m.group(2)
-        nbytes = 0
-        for sm in _SHAPE_RE.finditer(shapes):
-            dt, dims = sm.group(1), sm.group(2)
-            if dt not in _DTYPE_BYTES:
-                continue
-            n = 1
-            for d in dims.split(","):
-                if d:
-                    n *= int(d)
-            nbytes += n * _DTYPE_BYTES[dt]
-        out[kind] = out.get(kind, 0) + nbytes
-        out["total"] = out.get("total", 0) + nbytes
-        out.setdefault("count", 0)
-        out["count"] += 1
-    return out
-
-
-def scale_depth(cfg, d: int, unroll: bool = True):
-    """Same-architecture config with depth = d 'units' (see unit_count).
-
-    ``unroll=True`` additionally unrolls the layer scans so HloCostAnalysis
-    counts every layer — required for the two-point depth extrapolation."""
-    kw = {"scan_unroll": unroll}
-    if cfg.family == "hybrid":
-        rem = cfg.num_layers % cfg.attn_every
-        kw["num_layers"] = d * cfg.attn_every + rem
-    elif cfg.family in ("encdec", "audio"):
-        kw["num_layers"] = d
-        kw["encoder_layers"] = d
-    else:
-        kw["num_layers"] = d
-    return dataclasses.replace(cfg, **kw)
-
-
-def unit_count(cfg) -> int:
-    if cfg.family == "hybrid":
-        return cfg.num_layers // cfg.attn_every
-    return cfg.num_layers
-
-
-def lower_cell(cfg, shape_name: str, mesh, donate: bool = True):
-    """Build + lower the right step function for one cell. Returns lowered."""
-    seq, gbatch, kind = SHAPES[shape_name]
-    model = get_model(cfg)
-    key = jax.random.PRNGKey(0)
-    full_batch = kind == "train"
-
-    with mesh, sharding_context(mesh, full_batch=full_batch):
-        params_sds = jax.eval_shape(model.init, key)
-        batch_sds = input_specs(cfg, shape_name, gbatch, seq)
-        b_sh = meshlib.batch_shardings(batch_sds, mesh,
-                                       full_batch=full_batch)
-
-        if kind == "train":
-            opt_sds = jax.eval_shape(
-                lambda p: adamw_init(p, cfg.moment_dtype), params_sds)
-            p_sh, o_sh = steplib.train_state_shardings(
-                model, mesh, params_sds, opt_sds)
-            step = steplib.build_train_step(model)
-            fn = jax.jit(step, in_shardings=(p_sh, o_sh, b_sh),
-                         donate_argnums=(0, 1) if donate else ())
-            return fn.lower(params_sds, opt_sds, batch_sds)
-
-        cache_len = seq
-        cache_sds = jax.eval_shape(lambda: model.init_cache(gbatch, cache_len))
-        c_sh = steplib.cache_shardings(model, mesh, cache_sds)
-        p_sh = meshlib.sanitize_shardings(model.specs(), params_sds, mesh)
-        if kind == "prefill":
-            step = steplib.build_prefill_step(model)
-        else:
-            step = steplib.build_decode_step(model)
-        fn = jax.jit(step, in_shardings=(p_sh, c_sh, b_sh),
-                     donate_argnums=(1,) if donate else ())
-        return fn.lower(params_sds, cache_sds, batch_sds)
 
 
 def analyze(compiled) -> dict:
@@ -162,43 +56,6 @@ def analyze(compiled) -> dict:
     except Exception as e:  # pragma: no cover
         out["collectives"] = {"error": str(e)}
     return out
-
-
-def run_cell(arch: str, shape_name: str, multi_pod: bool,
-             roofline: bool = True, out_dir: str = OUT_DIR) -> dict:
-    mesh_name = "pod2x16x16" if multi_pod else "pod16x16"
-    cfg = get_config(arch)
-    if shape_name not in shapes_for(cfg):
-        return {"arch": arch, "shape": shape_name, "mesh": mesh_name,
-                "skipped": True,
-                "reason": "long_500k needs sub-quadratic attention"}
-    mesh = meshlib.make_production_mesh(multi_pod=multi_pod)
-    rec = {"arch": arch, "shape": shape_name, "mesh": mesh_name,
-           "n_devices": int(mesh.devices.size),
-           "units": unit_count(cfg), "skipped": False}
-
-    t0 = time.time()
-    lowered = lower_cell(cfg, shape_name, mesh)
-    rec["lower_s"] = round(time.time() - t0, 1)
-    t0 = time.time()
-    compiled = lowered.compile()
-    rec["compile_s"] = round(time.time() - t0, 1)
-    rec["full"] = analyze(compiled)
-    del lowered, compiled
-
-    if roofline and not multi_pod:
-        for d in (1, 2):
-            t0 = time.time()
-            c = lower_cell(scale_depth(cfg, d), shape_name, mesh).compile()
-            rec[f"depth{d}"] = analyze(c)
-            rec[f"depth{d}"]["compile_s"] = round(time.time() - t0, 1)
-            del c
-
-    os.makedirs(out_dir, exist_ok=True)
-    fn = os.path.join(out_dir, f"{arch}.{shape_name}.{mesh_name}.json")
-    with open(fn, "w") as f:
-        json.dump(rec, f, indent=1)
-    return rec
 
 
 def run_graph_cell(multi_pod: bool, out_dir: str = OUT_DIR,
@@ -244,55 +101,24 @@ def run_graph_cell(multi_pod: bool, out_dir: str = OUT_DIR,
 
 
 def main():
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default=None, help="one arch (default: all)")
-    ap.add_argument("--shape", default=None, help="one shape (default: all)")
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--graph", action="store_true",
+                    help="run the graph-engine cells")
     ap.add_argument("--multi-pod", action="store_true")
     ap.add_argument("--both-meshes", action="store_true")
-    ap.add_argument("--graph", action="store_true",
-                    help="also run the graph-engine cells")
-    ap.add_argument("--no-roofline", action="store_true",
-                    help="skip the depth-1/2 extrapolation compiles")
     ap.add_argument("--out", default=OUT_DIR)
-    ap.add_argument("--skip-existing", action="store_true")
     args = ap.parse_args()
-
-    archs = [args.arch] if args.arch else ARCHS
-    shapes = [args.shape] if args.shape else list(SHAPES)
-    meshes = [False, True] if args.both_meshes else [args.multi_pod]
+    if not args.graph:
+        ap.error("nothing to run: pass --graph")
 
     failures = []
-    for mp in meshes:
-        if args.graph:
-            try:
-                rec = run_graph_cell(mp, args.out)
-                print(f"[graph_engine {'2x16x16' if mp else '16x16'}] ok")
-            except Exception as e:
-                failures.append(("graph", mp, repr(e)))
-                traceback.print_exc()
-        for arch in archs:
-            for shape in shapes:
-                mesh_name = "pod2x16x16" if mp else "pod16x16"
-                fn = os.path.join(args.out, f"{arch}.{shape}.{mesh_name}.json")
-                if args.skip_existing and os.path.exists(fn):
-                    print(f"[{arch} {shape} {mesh_name}] cached")
-                    continue
-                try:
-                    rec = run_cell(arch, shape, mp,
-                                   roofline=not args.no_roofline,
-                                   out_dir=args.out)
-                    if rec.get("skipped"):
-                        print(f"[{arch} {shape} {mesh_name}] SKIP "
-                              f"({rec['reason']})")
-                    else:
-                        mem = rec["full"].get("memory", {})
-                        print(f"[{arch} {shape} {mesh_name}] ok "
-                              f"compile={rec['compile_s']}s "
-                              f"peak/dev={mem.get('peak_bytes', 0)/2**30:.2f}GiB")
-                except Exception as e:
-                    failures.append((arch, shape, mp, repr(e)))
-                    print(f"[{arch} {shape} {mesh_name}] FAIL: {e}")
-                    traceback.print_exc()
+    for mp in ([False, True] if args.both_meshes else [args.multi_pod]):
+        try:
+            run_graph_cell(mp, args.out)
+            print(f"[graph_engine {'2x16x16' if mp else '16x16'}] ok")
+        except Exception as e:
+            failures.append(("graph", mp, repr(e)))
+            traceback.print_exc()
     if failures:
         print(f"\n{len(failures)} FAILURES:")
         for f in failures:

@@ -1,15 +1,14 @@
-"""Production mesh construction and sharding-spec sanitization.
+"""Production mesh construction, and the graph engine's 1-D view of it.
 
 ``make_production_mesh`` is a FUNCTION (never a module-level constant) so
 importing this module never touches jax device state.
 """
 from __future__ import annotations
 
-import math
 from typing import Optional
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -21,11 +20,6 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
-def dp_axes(mesh: Mesh) -> tuple:
-    """The data-parallel axes: batch shards over (pod, data)."""
-    return tuple(a for a in ("pod", "data") if a in mesh.axis_names)
-
-
 def make_graph_mesh(mesh: Optional[Mesh] = None) -> Mesh:
     """The 1-D logical ``graph`` axis the sharded tile-grid engine
     (``repro.shard``) partitions over: every device of ``mesh`` flattened
@@ -34,62 +28,3 @@ def make_graph_mesh(mesh: Optional[Mesh] = None) -> Mesh:
     from repro.shard.tile_shard import as_graph_mesh
     return as_graph_mesh(mesh)
 
-
-def sanitize_spec(spec: P, shape: tuple, mesh: Mesh) -> P:
-    """Drop axes that are absent from the mesh or don't divide the dim."""
-    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
-    spec = tuple(spec) + (None,) * (len(shape) - len(spec))
-    out = []
-    for dim, entry in zip(shape, spec):
-        if entry is None:
-            out.append(None)
-            continue
-        names = entry if isinstance(entry, tuple) else (entry,)
-        names = tuple(n for n in names if n in sizes)
-        while names and dim % math.prod(sizes[n] for n in names) != 0:
-            names = names[:-1]
-        if not names:
-            out.append(None)
-        elif len(names) == 1:
-            out.append(names[0])
-        else:
-            out.append(names)
-    return P(*out)
-
-
-def sanitize_shardings(specs, shapes, mesh: Mesh):
-    """Tree of desired P -> tree of NamedSharding, validated against mesh.
-
-    ``shapes`` is a matching tree of arrays / ShapeDtypeStructs.
-    """
-    def one(spec, like):
-        if spec is None:
-            spec = P()
-        return NamedSharding(mesh, sanitize_spec(spec, like.shape, mesh))
-
-    return jax.tree.map(one, specs, shapes,
-                        is_leaf=lambda s: isinstance(s, P) or s is None)
-
-
-def batch_shardings(batch_shapes: dict, mesh: Mesh,
-                    full_batch: bool = False):
-    """Input batches: leading dim over the DP axes; training shards the
-    batch over EVERY axis (order data, model, pod — drop-from-end keeps
-    (data, model) when the pod axis doesn't divide, giving hierarchical DP
-    with pod-replicated batches).  M-RoPE positions carry a leading section
-    axis, so the batch dim is axis 1 there."""
-    if full_batch:
-        dp = tuple(a for a in ("data", "model", "pod")
-                   if a in mesh.axis_names)
-    else:
-        dp = dp_axes(mesh)
-    out = {}
-    for k, v in batch_shapes.items():
-        if k == "positions":
-            spec = P(None, dp)
-        elif v.ndim >= 1:
-            spec = P(dp)
-        else:
-            spec = P()
-        out[k] = NamedSharding(mesh, sanitize_spec(spec, v.shape, mesh))
-    return out

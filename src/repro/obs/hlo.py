@@ -16,9 +16,9 @@ cached (by default process-wide, shared across accountant instances — a
 re-created service must not recompile programs XLA already built this
 process) and attached to every subsequent query's trace record for free.
 
-The HLO text parser mirrors ``launch.dryrun.parse_collective_bytes`` but
-lives here import-free: dryrun prepends a 512-device XLA flag at import
-time, which must never leak into a serving process.
+The HLO text parser is also what ``launch/dryrun.py`` and
+``benchmarks/bench_shard.py`` read their collective bytes with; this module
+sets no XLA flag, so a serving process can import it safely.
 """
 from __future__ import annotations
 

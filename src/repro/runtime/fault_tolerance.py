@@ -1,12 +1,8 @@
-"""Fault tolerance: restartable step loop, heartbeat / straggler detection.
+"""Fault tolerance: heartbeat / straggler detection.
 
 At 1000+ nodes the *expected* state is that something is failing.  The
 posture here:
 
-  * **Checkpoint/restart** — `RestartableLoop` wraps any step function with
-    periodic async checkpoints and resume-from-latest; a crash (or SIGTERM
-    preemption) anywhere re-enters at the last committed version with
-    deterministic data (see data/pipeline.py).
   * **Straggler detection** — `HeartbeatMonitor` keeps a rolling window of
     latencies; a measurement slower than ``factor`` x the rolling median
     raises a straggler flag.  Its primary consumer is the serving stack:
@@ -14,23 +10,19 @@ posture here:
     kwarg of either graph service) and it watches **commit latency** —
     a slow ``apply_ops``/ring append flags the commit, bumps the
     ``scheduler_stragglers`` counter, and annotates the commit's trace
-    span with ``straggler=True``.  The training loop below wires the
-    same monitor around its step function.  On a real fleet the flag
-    feeds the cluster scheduler (recreate the slow host / shrink the
-    mesh); the *elastic restart* path it would trigger is exactly the
+    span with ``straggler=True``.  On a real fleet the flag feeds the
+    cluster scheduler (recreate the slow host / shrink the mesh); the
+    *elastic restart* path it would trigger is exactly the
     mesh-resharding restore in checkpoint/ (tested in tests/test_checkpoint).
   * **Elastic scaling** — nothing in the checkpoint format mentions the
     mesh: restore onto more/fewer chips = `restore_checkpoint(mesh=new)`.
 """
 from __future__ import annotations
 
-import signal
 import statistics
 import time
 from collections import deque
 from typing import Callable, Optional
-
-from repro.checkpoint import Checkpointer
 
 
 class HeartbeatMonitor:
@@ -61,55 +53,3 @@ class HeartbeatMonitor:
         self.window.append(dt)
         return dt
 
-
-class RestartableLoop:
-    """Run ``state = step_fn(state, step_idx)`` with checkpoint/restart.
-
-    ``state`` must be a pytree (params, opt, ...).  Preemption (SIGTERM) and
-    injected failures checkpoint-and-raise; calling ``run`` again resumes.
-    """
-
-    def __init__(self, ckpt_dir: str, step_fn, state_like,
-                 ckpt_every: int = 50, mesh=None, specs=None,
-                 monitor: Optional[HeartbeatMonitor] = None):
-        self.ckpt = Checkpointer(ckpt_dir)
-        self.step_fn = step_fn
-        self.state_like = state_like
-        self.ckpt_every = ckpt_every
-        self.mesh = mesh
-        self.specs = specs
-        self.monitor = monitor or HeartbeatMonitor()
-        self._preempted = False
-
-    def _handle_sigterm(self, *_):
-        self._preempted = True
-
-    def run(self, state, total_steps: int, start_step: int = 0,
-            fail_at: Optional[int] = None):
-        """Returns (final_state, last_step_done). ``fail_at`` injects a crash
-        (for tests / chaos drills)."""
-        prev = signal.signal(signal.SIGTERM, self._handle_sigterm)
-        try:
-            resume_step, restored = self.ckpt.restore_latest(
-                self.state_like, self.mesh, self.specs)
-            if restored is not None and resume_step >= start_step:
-                state, start_step = restored, resume_step
-            for step in range(start_step, total_steps):
-                if fail_at is not None and step == fail_at:
-                    raise RuntimeError(f"injected failure at step {step}")
-                self.monitor.start()
-                state = self.step_fn(state, step)
-                self.monitor.stop(step)
-                if (step + 1) % self.ckpt_every == 0 or self._preempted:
-                    self.ckpt.save(step + 1, state)
-                if self._preempted:
-                    self.ckpt.wait()
-                    raise SystemExit("preempted; checkpointed at step "
-                                     f"{step + 1}")
-            self.ckpt.save(total_steps, state, blocking=True)
-            return state, total_steps
-        finally:
-            # drain any in-flight async checkpoint so a crash/preemption
-            # always leaves a consistent latest-step index behind
-            self.ckpt.wait()
-            signal.signal(signal.SIGTERM, prev)

@@ -1,8 +1,11 @@
-"""Pure-python reference model of the paper's ADT (the sequential oracle)."""
+"""Pure-python reference model of the paper's ADT (the sequential oracle),
+and helpers that build an engine graph and its oracle from one op list."""
 from __future__ import annotations
 
 import math
 from collections import deque
+
+from repro.core import PUTE, PUTV, REME, REMV, apply_ops, make_graph
 
 INF = float("inf")
 
@@ -138,3 +141,22 @@ class GraphOracle:
             for v, d in self.bc_dependencies(s).items():
                 scores[v] += d
         return scores
+
+
+def apply_pair(g, o, ops, batch_size=None):
+    """Commit ``ops`` to ``g`` as one batch and replay them on the oracle
+    ``o`` in the batch's linearization order: vertex ops, then edge ops."""
+    g, _ = apply_ops(g, ops, batch_size)
+    step = {PUTV: o.put_v, REMV: o.rem_v, PUTE: o.put_e, REME: o.rem_e}
+    for kind, *args in sorted(ops, key=lambda op: op[0] in (PUTE, REME)):
+        step[kind](*args)
+    return g, o
+
+
+def build_pair(n, edges, vcap=None, ecap=None, batch_size=None):
+    """Vertices ``0..n-1`` and the weighted arcs ``edges``, committed to a
+    fresh graph (capacities sized to fit unless given) and to an oracle."""
+    g = make_graph(vcap or max(16, n), ecap or max(16, 4 * len(edges)))
+    ops = [(PUTV, v) for v in range(n)]
+    ops += [(PUTE, u, v, w) for u, v, w in edges]
+    return apply_pair(g, GraphOracle(), ops, batch_size)

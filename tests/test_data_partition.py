@@ -1,4 +1,4 @@
-"""Data pipeline determinism + distributed graph queries + dryrun units.
+"""Distributed graph queries + the HLO collective-byte parser.
 
 The distributed-query tests run the tile-grid path (``core.partition``,
 rebased onto ``repro.shard`` in PR 3) on a single-device graph mesh — the
@@ -23,7 +23,6 @@ from repro.core.partition_legacy import (
     make_distributed_query as legacy_distributed_query,
     shard_edges,
 )
-from repro.data import SyntheticTokens
 from repro.shard import as_graph_mesh
 
 
@@ -34,17 +33,6 @@ def _ring_graph():
                         for i in range(8)]
                      + [(PUTE, 0, 5, 1.0)])
     return g
-
-
-def test_pipeline_determinism_across_restarts():
-    ds1 = SyntheticTokens(vocab_size=100, seq_len=16, global_batch=4, seed=7)
-    ds2 = SyntheticTokens(vocab_size=100, seq_len=16, global_batch=4, seed=7)
-    b1 = ds1.batch_at(41)["tokens"]
-    b2 = ds2.batch_at(41)["tokens"]
-    assert np.array_equal(b1, b2)
-    assert b1.shape == (4, 17)   # seq_len + 1 (inputs+targets)
-    assert not np.array_equal(b1, ds1.batch_at(42)["tokens"])
-    assert b1.min() >= 1 and b1.max() < 100
 
 
 def test_distributed_query_equals_local():
@@ -120,7 +108,7 @@ def test_legacy_edge_sharded_oracle_equals_local():
 
 
 def test_collective_parser():
-    from repro.launch.dryrun import parse_collective_bytes
+    from repro.obs.hlo import parse_collective_bytes
     hlo = """
       %ag = bf16[8,512,336]{2,1,0} all-gather(%x), replica_groups=...
       %ar = f32[1024]{0} all-reduce(%y), to_apply=%sum
@@ -139,29 +127,3 @@ def test_collective_parser():
     assert out["count"] == 6
     assert out["total"] == sum(v for k, v in out.items()
                                if k not in ("total", "count"))
-
-
-def test_sanitize_spec_divisibility():
-    from jax.sharding import PartitionSpec as P
-    from repro.launch.mesh import sanitize_spec
-    mesh = Mesh(np.asarray(jax.devices()[:1]).reshape(1, 1),
-                ("data", "model"))
-    # axis absent from mesh -> dropped
-    assert sanitize_spec(P("pod", "model"), (8, 8), mesh) == P(None, "model")
-    # 1-sized axes always divide
-    assert sanitize_spec(P("data"), (7,), mesh) == P("data")
-
-
-def test_scale_depth_and_units():
-    from repro.launch.dryrun import scale_depth, unit_count
-    from repro.configs import get_config
-    z = get_config("zamba2_12b")
-    assert unit_count(z) == 6                      # 38 // 6
-    z1 = scale_depth(z, 1)
-    assert z1.num_layers == 1 * 6 + 2              # keeps the tail
-    w = get_config("whisper_large_v3")
-    w2 = scale_depth(w, 2)
-    assert w2.num_layers == 2 and w2.encoder_layers == 2
-    q = get_config("qwen3_32b")
-    assert scale_depth(q, 2).num_layers == 2
-    assert scale_depth(q, 2).scan_unroll

@@ -16,24 +16,10 @@ from repro.core.queries import bc_level_cut, bfs_tree_parents, \
     sssp_tree_parents
 from repro.data import load_rmat_graph, rmat_edges
 from repro.engine.incremental import _delta_bc_at_cut, delta_bfs, delta_sssp
-from oracle import GraphOracle
+from oracle import build_pair
 import queries_reference
 
 INF = float("inf")
-
-
-def build_pair(n, edges):
-    g = make_graph(max(16, n), max(16, 4 * len(edges)))
-    o = GraphOracle()
-    ops = [(PUTV, v) for v in range(n)]
-    ops += [(PUTE, u, v, w) for u, v, w in edges]
-    g, _ = apply_ops(g, ops)
-    for op in ops:
-        if op[0] == PUTV:
-            o.put_v(op[1])
-        else:
-            o.put_e(op[1], op[2], op[3])
-    return g, o
 
 
 def rand_graph(seed, n=24, m=80, weighted=True):

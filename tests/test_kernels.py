@@ -202,61 +202,6 @@ def test_raw_kernels_default_interpret_from_backend():
     assert backend.resolve_interpret(False) is False
 
 
-@pytest.mark.parametrize("b,hq,hkv,sq,skv,d", [
-    (1, 4, 4, 32, 32, 16),     # MHA square
-    (2, 4, 2, 37, 53, 16),     # GQA ragged
-    (1, 8, 1, 16, 64, 32),     # MQA decode-ish (ends aligned)
-    (2, 2, 2, 1, 40, 16),      # single-query decode
-])
-def test_flash_attention_causal(b, hq, hkv, sq, skv, d):
-    q = RNG.standard_normal((b, hq, sq, d)).astype(np.float32)
-    k = RNG.standard_normal((b, hkv, skv, d)).astype(np.float32)
-    v = RNG.standard_normal((b, hkv, skv, d)).astype(np.float32)
-    out = ops.flash_attention(*map(jnp.asarray, (q, k, v)), bq=16, bk=16)
-    exp = ref.flash_attention_ref(*map(jnp.asarray, (q, k, v)))
-    assert np.max(np.abs(np.asarray(out) - np.asarray(exp))) < 3e-5
-
-
-def test_flash_attention_noncausal():
-    q = RNG.standard_normal((1, 2, 24, 16)).astype(np.float32)
-    k = RNG.standard_normal((1, 2, 40, 16)).astype(np.float32)
-    v = RNG.standard_normal((1, 2, 40, 16)).astype(np.float32)
-    out = ops.flash_attention(*map(jnp.asarray, (q, k, v)), causal=False,
-                              bq=16, bk=16)
-    exp = ref.flash_attention_ref(*map(jnp.asarray, (q, k, v)), causal=False)
-    assert np.max(np.abs(np.asarray(out) - np.asarray(exp))) < 3e-5
-
-
-def test_flash_attention_window():
-    q = RNG.standard_normal((1, 2, 48, 16)).astype(np.float32)
-    k = RNG.standard_normal((1, 2, 48, 16)).astype(np.float32)
-    v = RNG.standard_normal((1, 2, 48, 16)).astype(np.float32)
-    out = ops.flash_attention(*map(jnp.asarray, (q, k, v)), window=8,
-                              bq=16, bk=16)
-    # windowed oracle
-    lg = np.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(16)
-    i = np.arange(48)[:, None]
-    j = np.arange(48)[None, :]
-    m = (j <= i) & (j > i - 8)
-    lg = np.where(m[None, None], lg, -np.inf)
-    p = np.exp(lg - lg.max(-1, keepdims=True))
-    p /= p.sum(-1, keepdims=True)
-    exp = np.einsum("bhqk,bhkd->bhqd", p, v)
-    assert np.max(np.abs(np.asarray(out) - exp)) < 3e-5
-
-
-def test_flash_attention_bf16():
-    q = RNG.standard_normal((1, 2, 32, 16)).astype(np.float32)
-    k = RNG.standard_normal((1, 2, 32, 16)).astype(np.float32)
-    v = RNG.standard_normal((1, 2, 32, 16)).astype(np.float32)
-    qb, kb, vb = (jnp.asarray(x, jnp.bfloat16) for x in (q, k, v))
-    out = ops.flash_attention(qb, kb, vb, bq=16, bk=16)
-    exp = ref.flash_attention_ref(qb, kb, vb)
-    assert out.dtype == jnp.bfloat16
-    assert np.max(np.abs(np.asarray(out, np.float32)
-                         - np.asarray(exp, np.float32))) < 3e-2
-
-
 # ---------------- shift merge (the commit's table shift) ----------------
 
 @pytest.mark.parametrize("n,n_pos,n_app", [

@@ -639,7 +639,7 @@ assert np.allclose(np.asarray(dr.scores), np.asarray(dg.scores),
                    rtol=1e-5, atol=1e-5)
 
 # ---- collective-byte regression off the compiled HLO ----------------
-from repro.launch.dryrun import parse_collective_bytes
+from repro.obs.hlo import parse_collective_bytes
 def coll(kind, extra=(), src_chunk=None):
     fn = query_fn(mesh, kind, 16, False, src_chunk)
     lowered = fn.lower(view.w, view.occ, g.alive, g.ecnt, srcs, g.version,
